@@ -43,6 +43,7 @@ from pathlib import Path
 import numpy as np
 
 from .common import PCFG, dataset, emit, queries, time_queries
+from repro.compile_cache import enable_compile_cache
 from repro.core import (COAXIndex, CoaxConfig, ColumnFiles, FullScan, STRTree,
                         UniformGrid, point_rect)
 from repro.data import knn_rect_queries
@@ -183,10 +184,6 @@ def run_batch(rows: int = 100_000, n_queries: int = 256,
     hit_counts = {}
     for bk in backends:
         if bk == "device":
-            from repro.engine import device_available
-            if not device_available():
-                emit("batch/airline/device", 0.0, "skipped: jax unavailable")
-                continue
             result["device_qps"] = {}
             result["device_speedup"] = {}
         qps_key = "batch_qps" if bk == "numpy" else "device_qps"
@@ -240,7 +237,7 @@ def run_batch(rows: int = 100_000, n_queries: int = 256,
             assert best_batch >= single_qps, (
                 f"batch path regressed: {best_batch:.0f} qps < per-query "
                 f"loop {single_qps:.0f} qps")
-        assert hit_counts, "smoke ran no backend sweep (jax unavailable?)"
+        assert hit_counts, "smoke ran no backend sweep"
         counts = set(hit_counts.values())
         assert len(counts) == 1, f"backends disagree on hit counts: {hit_counts}"
         if result.get("device_speedup"):
@@ -283,10 +280,6 @@ def run_sharded(rows: int = 100_000, n_queries: int = 256,
 
     if backend not in ("numpy", "device"):
         raise ValueError(f"--shards sweeps one backend at a time, got {backend!r}")
-    if backend == "device":
-        from repro.engine import device_available
-        if not device_available():
-            raise RuntimeError("--backend device requested but jax is unavailable")
     ds = dataset("airline", rows)
     rects = np.asarray(queries("airline", rows, n_queries, PCFG.knn_k))
     single = COAXIndex(ds.data, backend=backend)
@@ -451,13 +444,11 @@ def run_mixed(rows: int = 50_000, n_queries: int = 192,
             want = oracle.query_batch_split(np.asarray(rects))
             assert all(np.array_equal(g, w) for g, w in zip(got, want)), \
                 f"mixed-wave hits disagree with scratch oracle at r={ratio}"
-            from repro.engine import device_available
-            if device_available():
-                idx.backend = "device"
-                got_d = idx.query_batch_split(np.asarray(rects))
-                idx.backend = "numpy"
-                assert all(np.array_equal(g, w) for g, w in zip(got_d, want)), \
-                    f"device mixed-wave hits disagree with oracle at r={ratio}"
+            idx.backend = "device"
+            got_d = idx.query_batch_split(np.asarray(rects))
+            idx.backend = "numpy"
+            assert all(np.array_equal(g, w) for g, w in zip(got_d, want)), \
+                f"device mixed-wave hits disagree with oracle at r={ratio}"
             assert s["writes_applied"] > 0 and s["rows_inserted"] > 0
             emit(f"mixed/airline/smoke@r{ratio}", 1.0,
                  f"oracle agreement ok ({len(rects)} rects)")
@@ -968,7 +959,8 @@ def run_telemetry(rows: int = 100_000, n_queries: int = 512, batch: int = 64,
     return result
 
 
-if __name__ == "__main__":
+def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--batch", action="store_true",
                     help="throughput mode: QPS vs batch size + BENCH_queries.json")
@@ -995,7 +987,7 @@ if __name__ == "__main__":
                     help="small sweep + hard throughput/agreement asserts (CI)")
     ap.add_argument("--rows", type=int, default=None)
     ap.add_argument("--queries", type=int, default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.telemetry:
         run_telemetry(rows=args.rows or 100_000,
                       n_queries=args.queries or (256 if args.smoke else 512),
@@ -1034,3 +1026,7 @@ if __name__ == "__main__":
                   backend=args.backend, smoke=args.smoke)
     else:
         run(rows=args.rows, n_queries=args.queries)
+
+
+if __name__ == "__main__":
+    main()

@@ -135,7 +135,6 @@ class COAXIndex:
         self.keep_dims = reduced_dims(self.n_dims, self.groups)
         self._device_opts = device_opts
         self._coax_plan = None          # engine.device.CoaxDevicePlan (lazy)
-        self._device_plan_failed = False
         self.last_batch_stats = BatchStats()
         self.durable = None             # storage.Durability, via attach_durability
         self._init_write_state()
@@ -272,10 +271,10 @@ class COAXIndex:
         """Adopt a ``_fit_state`` result as the CURRENT epoch — the atomic
         serving-thread half of the §5.4 handoff.  Swapping ``primary`` /
         ``outlier`` is what invalidates any frozen device plan (identity
-        check in ``_device_plan_obj``); fresh delta planes are keyed on the
+        check in ``device_plan``); fresh delta planes are keyed on the
         new groups' first dependent (``_delta_key_dim``).  The stale device
         plan is deliberately KEPT on ``_coax_plan``: the identity check in
-        ``_device_plan_obj`` rebuilds against the new grids on the next
+        ``device_plan`` rebuilds against the new grids on the next
         wave, and the rebuild ``adopt()``s the stale plan's jit cache so a
         compaction costs zero recompiles (pow2-bucketed image shapes)."""
         self.data = fitted["data"]
@@ -808,7 +807,6 @@ class COAXIndex:
         idx.keep_dims = reduced_dims(idx.n_dims, idx.groups)
         idx._device_opts = device_opts
         idx._coax_plan = None
-        idx._device_plan_failed = False
         idx.last_batch_stats = BatchStats()
         idx.durable = None
         idx.primary = GridFile.from_state(state["primary"],
@@ -933,9 +931,10 @@ class COAXIndex:
         ``backend="device"`` serves the wave from the §4 COAX device plan —
         primary + outlier + delta/tombstone scan fused into ONE kernel
         launch (``query_batch_submit`` + ``query_batch_collect``, which
-        pipelined callers may drive directly to overlap waves); waves whose
-        candidate cells overflow ``cell_cap`` fall back to the host path.
-        Either way the answer is bit-identical to the numpy backend.
+        pipelined callers may drive directly to overlap waves); on the
+        CPU-oracle route, waves whose candidate cells overflow ``cell_cap``
+        are answered on the host path.  Either way the answer is
+        bit-identical to the numpy backend.
 
         With an attached ``SemanticCache`` (``attach_cache``) the wave is
         consulted first (DESIGN.md §9.2): exact/contained rects answer from
@@ -1168,25 +1167,22 @@ class COAXIndex:
     # Device wave pipelining (DESIGN.md §4): submit launches the fused
     # kernel without transferring results; collect is the drain point.
     # ------------------------------------------------------------------ #
-    def _device_plan_obj(self):
-        """Lazily (re)build the §4 COAX device plan for the CURRENT epoch
-        grids; compaction swaps the grids, which invalidates by identity.
-        Warns once and degrades to the host path when jax is unavailable."""
-        if self._device_plan_failed:
-            return None
+    def device_plan(self):
+        """The §4 COAX device plan for the CURRENT epoch grids, built and
+        uploaded on first use; compaction swaps the grids, which
+        invalidates by identity.  The stale plan's device images are freed
+        BEFORE the new epoch uploads, so one epoch's image is resident at
+        a time.  A failed build or upload raises: the device backend never
+        degrades to the host path in silence."""
+        from ..engine.device import CoaxDevicePlan
+
         plan = self._coax_plan
         if (plan is not None and plan.primary is self.primary
                 and plan.outlier is self.outlier):
             return plan
-        try:
-            from ..engine.device import CoaxDevicePlan
-            fresh = CoaxDevicePlan(self, **(self._device_opts or {}))
-        except Exception as e:  # pragma: no cover - jax-less installs
-            import warnings
-            warnings.warn(f"device backend unavailable ({e}); using numpy path")
-            self._device_plan_failed = True
-            self._coax_plan = None
-            return None
+        if plan is not None:
+            plan.release_images()
+        fresh = CoaxDevicePlan(self, **(self._device_opts or {}))
         if plan is not None:       # carry counters AND the jit cache across
             fresh.adopt(plan)      # epoch swaps (no recompile per epoch)
         self._coax_plan = fresh
@@ -1196,9 +1192,9 @@ class COAXIndex:
                            nav: Optional[np.ndarray] = None):
         """Launch one device wave (ONE kernel dispatch) and return a handle
         for ``query_batch_collect`` — results stay device-resident until
-        then.  Waves the plan cannot serve (``cell_cap`` overflow, device
-        unavailable) are answered synchronously here by the host path, so
-        the handle ALWAYS reflects this submit's snapshot+delta state even
+        then.  Waves the plan declines (a ``cell_cap`` overflow on the
+        CPU-oracle route) are answered synchronously here by the host path,
+        so the handle ALWAYS reflects this submit's snapshot+delta state even
         if writes land before collection (per-wave snapshot semantics).
         A finished background build is folded in HERE, before the wave's
         snapshot is captured — wave-boundary handoff visibility (§5.4).
@@ -1227,15 +1223,12 @@ class COAXIndex:
                          nav: Optional[np.ndarray] = None):
         if nav is None:
             nav = self.translate_batch(rects) if rects.shape[0] else None
-        fallbacks = 0
         if rects.shape[0]:
-            plan = self._device_plan_obj()
-            if plan is not None:
-                ticket = plan.submit_wave(nav, rects)
-                if ticket is not None:
-                    return ("dev", plan, ticket)
-                fallbacks = 1                  # cell_cap overflow -> host
-            q, r, stats = self._query_batch_host(rects, nav, fallbacks)
+            plan = self.device_plan()
+            ticket = plan.submit_wave(nav, rects)
+            if ticket is not None:
+                return ("dev", plan, ticket)
+            q, r, stats = self._query_batch_host(rects, nav, fallbacks=1)
         else:
             q = r = np.empty(0, np.int64)
             stats = BatchStats(backend=self.backend)

@@ -137,11 +137,12 @@ class BatchStats:
     ``cells_probed``/``rows_scanned`` come from the index's planning stage
     (candidate (query, cell) pairs and scan-window rows respectively), so
     backend comparisons can report work done, not just wall-clock QPS.
-    ``fallbacks`` counts device waves that overflowed ``cell_cap`` and were
-    re-answered by the numpy path (DESIGN.md §4 submit-time overflow
-    contract); ``hit_overflows`` counts individual queries whose exact
-    device hit count exceeded ``hit_cap`` and were re-answered on the host
-    at drain time (§4 drain-time overflow contract).
+    ``fallbacks`` counts CPU-oracle device waves that overflowed
+    ``cell_cap`` and were answered by the numpy path (DESIGN.md §4
+    submit-time overflow contract; the Pallas route has none);
+    ``hit_overflows`` counts individual queries whose exact device hit
+    count exceeded ``hit_cap`` and were re-answered on the host at drain
+    time (§4 drain-time overflow contract).
     """
     queries: int = 0
     cells_probed: int = 0
@@ -177,10 +178,10 @@ class GridFile:
     row_ids : original identities of ``data`` rows (defaults to arange(N)).
     backend : ``"numpy"`` (default, the exact host path and correctness
         oracle) or ``"device"`` — route ``query_batch`` through the frozen
-        jitted device plan (DESIGN.md §4), falling back to numpy when a
-        wave's candidate cells overflow the plan's cap.
+        jitted device plan (DESIGN.md §4); on the CPU-oracle route a wave
+        whose candidate cells overflow the plan's cap is answered by numpy.
     device_opts : kwargs for ``engine.device.DevicePlan`` (cell_cap,
-        hit_cap, tile, min_bucket, use_pallas, interpret).
+        hit_cap, min_bucket, use_pallas, interpret).
     epoch : snapshot version label (DESIGN.md §5).  A grid file is an
         immutable snapshot of one epoch; the mutable lifecycle
         (``COAXIndex.compact``) replaces it with a new-epoch instance, which
@@ -262,7 +263,6 @@ class GridFile:
         self.last_batch_stats = BatchStats()
         self.device_opts = device_opts
         self._device_plan = None
-        self._device_plan_failed = False
         self.backend = backend
 
     # ------------------------------------------------------------------ #
@@ -321,7 +321,6 @@ class GridFile:
         gf.last_batch_stats = BatchStats()
         gf.device_opts = device_opts
         gf._device_plan = None
-        gf._device_plan_failed = False
         gf.backend = backend
         return gf
 
@@ -340,19 +339,11 @@ class GridFile:
     def device_plan(self):
         """Lazily-built frozen device plan (engine.device.DevicePlan).
 
-        Built (and uploaded) once on first use; ``None`` when jax is
-        unavailable, in which case the device backend silently degrades to
-        the numpy path.
+        Built (and uploaded) once on first use; a failed build raises.
         """
-        if self._device_plan is None and not self._device_plan_failed:
-            try:
-                from ..engine.device import DevicePlan
-                self._device_plan = DevicePlan(self, **(self.device_opts or {}))
-            except ImportError as e:
-                import warnings
-                warnings.warn(
-                    f"device backend unavailable ({e}); using numpy path")
-                self._device_plan_failed = True
+        if self._device_plan is None:
+            from ..engine.device import DevicePlan
+            self._device_plan = DevicePlan(self, **(self.device_opts or {}))
         return self._device_plan
 
     # ------------------------------------------------------------------ #
@@ -512,25 +503,24 @@ class GridFile:
         ``backend="device"`` routes through the frozen jitted device plan
         (DESIGN.md §4) under the contract that ``nav_rects``
         over-approximates ``filter_rects`` on the indexed dims — true for
-        Eq. 2 translation and for nav == filter; waves whose candidate
-        cells overflow the plan's cap fall back to this numpy path.
+        Eq. 2 translation and for nav == filter; on the CPU-oracle route,
+        waves whose candidate cells overflow the plan's cap are answered by
+        this numpy path.
         """
         nav_rects = np.asarray(nav_rects, dtype=np.float64)
         filter_rects = np.asarray(filter_rects, dtype=np.float64)
         b = nav_rects.shape[0]
         fallbacks = 0
         if self._backend == "device" and b:
-            plan = self.device_plan
-            if plan is not None:
-                res = plan.run_wave(nav_rects, filter_rects)
-                if res is not None:
-                    out_q, out_r, s = res
-                    self.last_batch_stats = BatchStats(
-                        queries=b, cells_probed=s["cells_probed"],
-                        rows_scanned=s["rows_scanned"], backend="device",
-                        hit_overflows=s.get("hit_overflows", 0))
-                    return out_q, out_r
-                fallbacks = 1                   # cell_cap overflow -> numpy
+            res = self.device_plan.run_wave(nav_rects, filter_rects)
+            if res is not None:
+                out_q, out_r, s = res
+                self.last_batch_stats = BatchStats(
+                    queries=b, cells_probed=s["cells_probed"],
+                    rows_scanned=s["rows_scanned"], backend="device",
+                    hit_overflows=s["hit_overflows"])
+                return out_q, out_r
+            fallbacks = 1                   # CPU-oracle cell_cap overflow
         return self._query_batch_numpy(nav_rects, filter_rects, fallbacks)
 
     def _query_batch_numpy(

@@ -27,8 +27,7 @@ behind one scatter-gather plane, each with its own FDs, delta and epochs.
                          launch per wave, hits compacted into device-
                          resident buffers and drained one wave behind the
                          submit (double-buffered by executor/server);
-                         imported lazily so the numpy engine works
-                         without jax
+                         imported lazily, on first device use
 """
 from .cache import CacheLookup, EpochPin, SemanticCache, ShardedEpochPin
 from .executor import BatchQueryExecutor, WaveStats, split_hits
@@ -49,12 +48,11 @@ __all__ = [
     "ShardedEpochPin",
     "DevicePlan",
     "CoaxDevicePlan",
-    "device_available",
 ]
 
 
 def __getattr__(name):  # PEP 562: keep jax out of the default import path
-    if name in ("DevicePlan", "CoaxDevicePlan", "device_available"):
+    if name in ("DevicePlan", "CoaxDevicePlan"):
         from . import device
         return getattr(device, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

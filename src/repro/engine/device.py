@@ -15,17 +15,18 @@ kernel evaluates alongside the exact full-predicate filter and the liveness
 mask — ``hit = alive ∧ candidate ∧ inside`` — and the nav⊇filter invariant
 makes the result bit-identical to numpy.
 
-Frozen per-grid image (``_GridImage``, uploaded once per epoch):
-  * ``rows_t``  (D, N_pad) f32 records, ``+inf``-padded to a tile multiple;
-  * ``coords``  (k, N_pad) i32 per-dim cell coordinate of every row (the
+Frozen per-grid image (``_GridImage``, uploaded once per epoch), every
+per-row plane in the kernel's lane layout ``(·, N_pad/128, 128)``
+(``kernels.fused_scan.to_lanes``), ``N_pad`` a power of two >= 4096:
+  * ``rows``    (D, ·) f32 records, ``+inf``-padded;
+  * ``coords``  (k, ·) i32 per-dim cell coordinate of every row (the
     device twin of the directory: mixed-radix decode of each row's cell);
-  * ``sv``      (1, N_pad) f32 in-cell sorted attribute;
-  * ``alive``   (1, N_pad) i32 liveness (tombstones re-uploaded only when
-    the tombstone counters move);
+  * ``sv``      (·) f32 in-cell sorted attribute;
+  * ``alive``   (·) i32 liveness (tombstones re-uploaded only when the
+    tombstone counters move);
   * host f32 edge images (``f32_ceil``/``f32_floor`` paired rounding) for
     the ONE conservative host directory pass per wave that yields
-    [first, last], the ``cell_cap`` overflow pre-check AND the
-    ``cells_probed`` stat (previously two passes).
+    [first, last] and the ``cells_probed`` stat.
 
 Per wave, every segment — primary grid, outlier grid, and the fixed-shape
 delta/tombstone image of the live append log — goes into ONE jitted
@@ -33,24 +34,28 @@ delta/tombstone image of the live append log — goes into ONE jitted
 wave).  On the CPU-oracle route the grid segments additionally ship
 per-query candidate gather-index images (and skew-split into thin/fat
 sub-segments, still one dispatch) so per-wave work scales with candidate
-counts, not table size — DESIGN.md §4 "CPU oracle fast path".  Outputs stay device-resident and compacted (per-query hit count +
+counts, not table size — DESIGN.md §4 "CPU oracle fast path".  Outputs
+stay device-resident and compacted (per-query hit count +
 first ``hit_cap`` hit positions); nothing transfers until ``collect`` — the
 explicit drain point (``jax.block_until_ready``) — so a submitted wave can
 overlap the previous wave's drain (the executor/server double-buffering
 schedule, depth 2).
 
 Overflow contracts (both exact):
-  * ``cell_cap`` — detected at SUBMIT from the host probe; the whole wave
-    is answered by the numpy path (``fallbacks`` stat).
   * ``hit_cap``  — detected at DRAIN from the exact device counts; only the
     overflowing queries are re-answered on the host FROM CAPTURED STATE
     (frozen grids + the tombstone set and delta log captured at submit), so
     interleaved writes between submit and drain cannot shift the wave's
-    snapshot (``hit_overflows`` stat).
+    snapshot (``hit_overflows`` stat).  The re-answer must find exactly the
+    device's count, or the drain raises.
+  * ``cell_cap`` — the CPU-oracle route only, whose gather work grows with
+    candidate cells: detected at SUBMIT from the host probe; the whole wave
+    is answered by the numpy path (``fallbacks`` stat).  The Pallas route
+    scans every row whatever the probe says and answers every wave.
 
 Shape bucketing: wave width pads to a pow2 bucket (min ``min_bucket``),
-grid images to a pow2 row count (min ``tile``), and the delta image to
-``max(128, pow2)`` rows, so steady-state serving — and epoch swaps under
+grid and delta images to a pow2 row count (min 4096, one kernel word
+group), so steady-state serving — and epoch swaps under
 background compaction (§5.4), via ``_PlanBase.adopt`` — re-enter compiled
 executables; ``compile_count`` exposes the jit cache size for the
 regression test.
@@ -66,29 +71,19 @@ import functools
 import time
 from typing import Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
 from ..core.gridfile import BatchStats, f32_ceil
 from ..core.types import sorted_contains
+from ..kernels import ref
+from ..kernels._platform import on_cpu, resolve_interpret
+from ..kernels.fused_scan import (GROUP_ROWS, fused_scan_call, pad_to_lanes,
+                                  to_lanes)
 
-__all__ = ["DevicePlan", "CoaxDevicePlan", "device_available", "f32_floor"]
-
-try:  # the container bakes jax in; gate anyway so numpy-only installs work
-    import jax
-    import jax.numpy as jnp
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - exercised only without jax
-    jax = None
-    jnp = None
-    _HAVE_JAX = False
-
-DELTA_TILE = 128          # delta images bucket to max(128, pow2(m)) rows
-
-
-def device_available() -> bool:
-    """True when the jax runtime needed by the device plans is importable."""
-    return _HAVE_JAX
+__all__ = ["DevicePlan", "CoaxDevicePlan", "f32_floor"]
 
 
 def f32_floor(x: np.ndarray) -> np.ndarray:
@@ -124,7 +119,7 @@ def _wave_program(segs, config):
     """ONE wave = one dispatch of this jitted program over every segment.
 
     ``segs`` is a tuple of array dicts (a pytree), ``config`` the matching
-    tuple of static per-segment tuples ``(tile, hit_cap, probe, has_sort,
+    tuple of static per-segment tuples ``(hit_cap, probe, has_sort,
     use_pallas, interpret, gw)``.  Each segment runs the fused megakernel
     (the Pallas kernel on accelerators, its jnp oracle — same contract — on
     CPU; ``gw > 0`` additionally restricts the oracle to each query's
@@ -132,11 +127,8 @@ def _wave_program(segs, config):
     exactness-preserving CPU fast path) and returns its compacted
     ``(counts, hits, scanned)``.
     """
-    from ..kernels import ref
-    from ..kernels.fused_scan import fused_scan_call
-
     out = []
-    for seg, (tile, hit_cap, probe, has_sort, use_pallas, interpret,
+    for seg, (hit_cap, probe, has_sort, use_pallas, interpret,
               gw) in zip(segs, config):
         kwargs = {}
         if probe:
@@ -147,13 +139,13 @@ def _wave_program(segs, config):
         if use_pallas:
             out.append(fused_scan_call(
                 seg["rows"], seg["flo"], seg["fhi"], seg["alive"],
-                tile=tile, hit_cap=hit_cap, interpret=interpret, **kwargs))
+                hit_cap=hit_cap, interpret=interpret, **kwargs))
         else:
             if gw:
                 kwargs["gidx"] = seg["gidx"]
             out.append(ref.fused_scan_ref(
                 seg["rows"], seg["flo"], seg["fhi"], seg["alive"],
-                tile=tile, hit_cap=hit_cap, **kwargs))
+                hit_cap=hit_cap, **kwargs))
     return tuple(out)
 
 
@@ -161,11 +153,10 @@ class _GridImage:
     """Frozen device image of one ``GridFile`` epoch (uploaded once) plus
     the host-side conservative f32 directory for the per-wave probe."""
 
-    def __init__(self, grid, tile: int):
+    def __init__(self, grid):
         n, k = grid.n_rows, len(grid.grid_dims)
         c = grid.cells_per_dim
         self.grid = grid
-        self.tile = int(tile)
         self.n = n
         self.grid_pos = [grid.index_dims.index(d) for d in grid.grid_dims]
         self.sort_pos = (grid.index_dims.index(grid.sort_dim)
@@ -184,46 +175,48 @@ class _GridImage:
         # mixed-radix weights of the row-major cell id, for window bounds
         self._radix = c ** (k - 1 - np.arange(k, dtype=np.int64))
 
-        # pow2 bucket (min tile, kept a tile multiple for the kernel grid)
-        # with always >= 1 pad row: the gather-list fast path points pad
-        # slots at the last (dead, +inf) padded row, which must exist.
-        # Bucketing means epoch-over-epoch growth re-enters compiled wave
-        # shapes instead of minting one executable per compaction (§5.4).
-        n_pad = max(self.tile, _next_pow2(n + 1))
-        n_pad += (-n_pad) % self.tile
+        # pow2 bucket (min one 4096-row kernel group; every pow2 from there
+        # is a kernel grid size) with always >= 1 pad row: the gather-list
+        # fast path points pad slots at the last (dead, +inf) padded row,
+        # which must exist.  Bucketing means epoch-over-epoch growth
+        # re-enters compiled wave shapes instead of minting one executable
+        # per compaction (§5.4).  Planes are stored in the kernel's lane
+        # layout (``fused_scan.to_lanes``).
+        n_pad = max(GROUP_ROWS, _next_pow2(n + 1))
         self.n_pad = n_pad
-        pad = n_pad - n
-        rows_t = np.pad(grid.rows.T, ((0, 0), (0, pad)),
-                        constant_values=np.inf)
-        self.rows_t = jnp.asarray(rows_t, jnp.float32)
-        self.bytes_resident = rows_t.size * 4
+        rows = pad_to_lanes(grid.rows.T, n_pad, np.inf, np.float32)
+        self.rows = jnp.asarray(rows)
+        self.bytes_resident = rows.nbytes
+        del rows
         if self.probe:
             cell_of_row = np.repeat(
                 np.arange(grid.n_cells, dtype=np.int64), np.diff(grid.offsets))
-            coords = np.full((k, self.n_pad), -1, np.int32)
-            for j in range(k):                 # row-major decode, dim j digit
-                coords[j, :n] = (cell_of_row // c ** (k - 1 - j)) % c
+            coords = pad_to_lanes(             # row-major decode, dim j digit
+                np.stack([(cell_of_row // c ** (k - 1 - j)) % c
+                          for j in range(k)]), n_pad, -1, np.int32)
             self.coords = jnp.asarray(coords)
-            self.bytes_resident += coords.size * 4
+            self.bytes_resident += coords.nbytes
+            del coords, cell_of_row
         if self.has_sort:
-            sv = np.pad(grid.sort_vals, (0, pad), constant_values=np.inf)
-            self.sv = jnp.asarray(sv, jnp.float32)[None, :]
-            self.bytes_resident += sv.size * 4
+            sv = pad_to_lanes(grid.sort_vals, n_pad, np.inf, np.float32)
+            self.sv = jnp.asarray(sv)
+            self.bytes_resident += sv.nbytes
         self.bytes_resident += self.set_alive(None)
 
     # ------------------------------------------------------------------ #
     def set_alive(self, dead_ids: Optional[np.ndarray]) -> int:
         """(Re)upload the liveness mask — all-live, or ``row_ids`` minus the
         tombstone set.  Returns bytes uploaded."""
-        alive = np.zeros((1, self.n_pad), np.int32)
+        alive = np.zeros(self.n_pad, np.int32)
         if dead_ids is None or not dead_ids.size:
-            alive[0, :self.n] = 1
+            alive[:self.n] = 1
         else:
             # dead_ids is sorted (``COAXIndex._dead_ids``): binary-search
             # membership, no per-upload re-sort of the 50k-id base
-            alive[0, :self.n] = ~sorted_contains(dead_ids, self.grid.row_ids)
+            alive[:self.n] = ~sorted_contains(dead_ids, self.grid.row_ids)
+        alive = to_lanes(alive)
         self.alive = jnp.asarray(alive)
-        return alive.size * 4
+        return alive.nbytes
 
     def probe_batch(self, nav_rects: np.ndarray):
         """ONE host directory pass per wave: per-query per-dim [first, last]
@@ -301,8 +294,8 @@ class _GridImage:
         if qmask is not None:
             flo[:b][~qmask] = np.inf
             fhi[:b][~qmask] = -np.inf
-        seg = {"rows": self.rows_t, "alive": self.alive,
-               "flo": jnp.asarray(flo.T), "fhi": jnp.asarray(fhi.T)}
+        seg = {"rows": self.rows, "alive": self.alive,
+               "flo": jnp.asarray(flo), "fhi": jnp.asarray(fhi)}
         nbytes = flo.size * 8
         if self.probe:
             k = first.shape[1]
@@ -336,8 +329,18 @@ class _GridImage:
                    gw: int = 0) -> tuple:
         # the Pallas kernel path always scans full-N (the accelerator
         # design); the gather is the CPU oracle's candidate-scaling lever
-        return (self.tile, hit_cap, self.probe, self.has_sort,
+        return (hit_cap, self.probe, self.has_sort,
                 use_pallas, interpret, 0 if use_pallas else int(gw))
+
+
+def _check_reanswer(dev_counts: np.ndarray, host_q: np.ndarray) -> None:
+    """The device's per-query counts are exact even past ``hit_cap``; the
+    host re-answer of those queries must find exactly as many rows."""
+    host = np.bincount(host_q, minlength=dev_counts.size)
+    if not np.array_equal(host, dev_counts):
+        raise RuntimeError(
+            f"device hit counts {dev_counts.tolist()} disagree with the "
+            f"host re-answer {host.tolist()}")
 
 
 def _extract_hits(counts: np.ndarray, hits: np.ndarray, cap: int,
@@ -355,17 +358,12 @@ def _extract_hits(counts: np.ndarray, hits: np.ndarray, cap: int,
 class _PlanBase:
     """Knobs + counters shared by the grid-level and COAX-level plans."""
 
-    def _init_opts(self, cell_cap, tile, min_bucket, hit_cap,
-                   use_pallas, interpret):
-        if not _HAVE_JAX:
-            raise ImportError("jax is required for the device backend")
+    def _init_opts(self, cell_cap, min_bucket, hit_cap, use_pallas, interpret):
         self.cell_cap = int(cell_cap)
-        self.tile = int(tile)
         self.min_bucket = int(min_bucket)
         self.hit_cap = int(hit_cap)
-        on_cpu = jax.default_backend() == "cpu"
-        self.use_pallas = (not on_cpu) if use_pallas is None else bool(use_pallas)
-        self.interpret = on_cpu if interpret is None else bool(interpret)
+        self.use_pallas = (not on_cpu()) if use_pallas is None else bool(use_pallas)
+        self.interpret = resolve_interpret(interpret)
         # a fresh partial per plan keeps the jit cache (and compile_count)
         # private to this plan instead of shared process-wide
         self._fn = jax.jit(functools.partial(_wave_program),
@@ -389,12 +387,17 @@ class _PlanBase:
     @property
     def compile_count(self) -> int:
         """Distinct compiled wave shapes so far — the §4 cache-policy metric."""
-        if hasattr(self._fn, "_cache_size"):
-            return int(self._fn._cache_size())
-        return 0  # pragma: no cover - older jax without cache introspection
+        return int(self._fn._cache_size())
 
     def bucket(self, b: int) -> int:
         return max(self.min_bucket, _next_pow2(b))
+
+    def _over_cell_cap(self, n_cells_q: np.ndarray) -> bool:
+        """The CPU-oracle route's submit-time budget (its gather work grows
+        with candidate cells).  The Pallas route scans every row whatever
+        the probe says, so it never declines a wave."""
+        return (not self.use_pallas
+                and int(n_cells_q.max(initial=0)) > self.cell_cap)
 
     def _count_h2d(self, nbytes: int) -> None:
         """Fold an upload into the plan counter AND the global registry
@@ -466,12 +469,14 @@ class DevicePlan(_PlanBase):
     Parameters
     ----------
     grid : the host ``GridFile`` to freeze (arrays are uploaded once here).
-    cell_cap : per-query candidate-cell budget; waves where any query's
-        directory probe exceeds it return ``None`` from ``submit_wave`` so
-        the caller falls back to the numpy path (submit-time contract, §4).
+    cell_cap : per-query candidate-cell budget of the CPU-oracle route,
+        whose gather work grows with candidate cells: waves where any
+        query's directory probe exceeds it return ``None`` from
+        ``submit_wave`` so the caller answers them on the numpy path.  The
+        Pallas route scans every row whatever the probe says, so it has no
+        such budget and answers every wave (§4).
     hit_cap : per-query device hit-buffer budget; queries whose exact count
         exceeds it are re-answered on the host at drain time (§4).
-    tile : record tile width for the megakernel (N pads to a multiple).
     min_bucket : smallest wave bucket; B pads up to ``max(min_bucket,
         next_pow2(B))`` so steady-state widths share compiled shapes.
     use_pallas : route segments through the Pallas kernel; ``None`` picks
@@ -480,41 +485,29 @@ class DevicePlan(_PlanBase):
         tool rather than a fast path.
     """
 
-    def __init__(self, grid, *, cell_cap: int = 256, tile: int = 512,
+    def __init__(self, grid, *, cell_cap: int = 256,
                  min_bucket: int = 4, hit_cap: int = 1024,
                  use_pallas: Optional[bool] = None,
                  interpret: Optional[bool] = None):
-        self._init_opts(cell_cap, tile, min_bucket, hit_cap,
-                        use_pallas, interpret)
+        self._init_opts(cell_cap, min_bucket, hit_cap, use_pallas, interpret)
         self.grid = grid
         self.epoch = int(getattr(grid, "epoch", 0))   # snapshot version (§5)
         self.n_rows = grid.n_rows
-        self._img = _GridImage(grid, self.tile) if grid.n_rows else None
+        self._img = _GridImage(grid) if grid.n_rows else None
         if self._img is not None:
             self._count_h2d(self._img.bytes_resident)
 
     # ------------------------------------------------------------------ #
-    def plan_counts(self, nav_rects: np.ndarray,
-                    bounds: Optional[tuple] = None) -> np.ndarray:
-        """Per-query candidate-cell counts under the device probe (the same
-        conservative f32 rounding) — ``probe_batch``'s counts, exposed for
-        callers that only need the overflow pre-check / work stat."""
-        if self._img is None:
-            return np.ones(nav_rects.shape[0], np.int64)
-        del bounds                    # probe_batch recomputes; ONE pass total
-        return self._img.probe_batch(nav_rects)[2]
-
-    # ------------------------------------------------------------------ #
     def submit_wave(self, nav_rects: np.ndarray, filter_rects: np.ndarray):
         """Launch one wave (ONE dispatch); returns an opaque ticket for
-        ``collect``, or ``None`` on ``cell_cap`` overflow (caller falls back
-        to numpy).  No results transfer until ``collect``."""
+        ``collect``, or ``None`` on a CPU-oracle ``cell_cap`` overflow
+        (caller answers on numpy).  No results transfer until ``collect``."""
         b = nav_rects.shape[0]
         if b == 0 or self.n_rows == 0:
             return {"b": b, "res": None}
         first, last, n_cells_q = self._img.probe_batch(nav_rects)
-        if int(n_cells_q.max(initial=0)) > self.cell_cap:
-            return None                                # overflow fallback
+        if self._over_cell_cap(n_cells_q):
+            return None
         bp = self.bucket(b)
         glists, gw = None, 0
         if not self.use_pallas:
@@ -546,6 +539,7 @@ class DevicePlan(_PlanBase):
             qsel = np.nonzero(over)[0]
             qo, ro = self.grid._query_batch_numpy(
                 ticket["nav"][qsel], ticket["filt"][qsel])
+            _check_reanswer(counts[qsel], qo)
             rows_scanned += self.grid.last_batch_stats.rows_scanned
             out_q = np.concatenate([out_q, qsel[qo]])
             out_r = np.concatenate([out_r, ro])
@@ -557,8 +551,8 @@ class DevicePlan(_PlanBase):
 
     def run_wave(self, nav_rects: np.ndarray, filter_rects: np.ndarray
                  ) -> Optional[Tuple[np.ndarray, np.ndarray, dict]]:
-        """Submit + drain one wave synchronously; ``None`` on ``cell_cap``
-        overflow (the ``GridFile.query_batch`` fallback contract)."""
+        """Submit + drain one wave synchronously; ``None`` on a CPU-oracle
+        ``cell_cap`` overflow (``GridFile.query_batch`` answers on numpy)."""
         ticket = self.submit_wave(nav_rects, filter_rects)
         if ticket is None:
             return None
@@ -583,20 +577,17 @@ class CoaxDevicePlan(_PlanBase):
     host composition over the pin's frozen arrays (``engine.cache``).
     """
 
-    def __init__(self, index, *, cell_cap: int = 256, tile: int = 512,
+    def __init__(self, index, *, cell_cap: int = 256,
                  min_bucket: int = 4, hit_cap: int = 1024,
                  use_pallas: Optional[bool] = None,
                  interpret: Optional[bool] = None):
-        self._init_opts(cell_cap, tile, min_bucket, hit_cap,
-                        use_pallas, interpret)
+        self._init_opts(cell_cap, min_bucket, hit_cap, use_pallas, interpret)
         self.index = index
         self.primary = index.primary
         self.outlier = index.outlier
         self.epoch = int(index.epoch)
-        self.p_img = (_GridImage(self.primary, self.tile)
-                      if self.primary.n_rows else None)
-        self.o_img = (_GridImage(self.outlier, self.tile)
-                      if self.outlier.n_rows else None)
+        self.p_img = _GridImage(self.primary) if self.primary.n_rows else None
+        self.o_img = _GridImage(self.outlier) if self.outlier.n_rows else None
         for img in (self.p_img, self.o_img):
             if img is not None:
                 self._count_h2d(img.bytes_resident)
@@ -604,6 +595,12 @@ class CoaxDevicePlan(_PlanBase):
         self._dead_host = np.empty(0, np.int64)
         self._delta_key = None
         self._delta = None
+
+    def release_images(self) -> None:
+        """Drop this plan's device images once its epoch is superseded.
+        Waves already dispatched hold their own references until they
+        finish, and ``collect`` never reads the images."""
+        self.p_img = self.o_img = self._delta = None
 
     # ------------------------------------------------------------------ #
     def _refresh_writes(self) -> None:
@@ -625,15 +622,13 @@ class CoaxDevicePlan(_PlanBase):
             ids = np.concatenate([i1, i2])
             m = rows.shape[0]
             if m:
-                m_pad = max(DELTA_TILE, _next_pow2(m))   # bounded recompiles
-                rows_t = np.full((rows.shape[1], m_pad), np.inf, np.float32)
-                rows_t[:, :m] = rows.T
-                alive = np.zeros((1, m_pad), np.int32)
-                alive[0, :m] = 1
-                self._delta = {"rows_t": jnp.asarray(rows_t),
+                m_pad = max(GROUP_ROWS, _next_pow2(m))   # bounded recompiles
+                rows_l = pad_to_lanes(rows.T, m_pad, np.inf, np.float32)
+                alive = pad_to_lanes(np.ones(m, np.int32), m_pad, 0, np.int32)
+                self._delta = {"rows_l": jnp.asarray(rows_l),
                                "alive": jnp.asarray(alive),
-                               "rows": rows, "ids": ids, "m_pad": m_pad}
-                self._count_h2d(rows_t.size * 4 + alive.size * 4)
+                               "rows": rows, "ids": ids}
+                self._count_h2d(rows_l.nbytes + alive.nbytes)
             else:
                 self._delta = None
             self._delta_key = delta_key
@@ -700,8 +695,8 @@ class CoaxDevicePlan(_PlanBase):
     def submit_wave(self, nav_rects: np.ndarray, rects: np.ndarray):
         """Launch one COAX wave (ONE dispatch over up to three segments —
         plus thin/fat splits of the grid segments on the CPU-oracle path);
-        returns a ticket for ``collect`` or ``None`` on ``cell_cap``
-        overflow.  All snapshot/write state the drain needs is captured
+        returns a ticket for ``collect`` or ``None`` on a CPU-oracle
+        ``cell_cap`` overflow.  All snapshot/write state the drain needs is captured
         here, synchronously — per-wave snapshot semantics (§5)."""
         b = rects.shape[0]
         if b == 0:
@@ -714,7 +709,7 @@ class CoaxDevicePlan(_PlanBase):
 
         if self.p_img is not None:
             first, last, ncq = self.p_img.probe_batch(nav_rects)
-            if int(ncq.max(initial=0)) > self.cell_cap:
+            if self._over_cell_cap(ncq):
                 return None
             cells_probed += int(ncq.sum())
             nbytes += self._add_grid_segs(self.p_img, self.primary.row_ids,
@@ -732,7 +727,7 @@ class CoaxDevicePlan(_PlanBase):
             # nav == full rect for the full-dim outlier grid
             of, ol, oncq = self.o_img.probe_batch(rects)
             oncq = np.where(touch, oncq, 0)
-            if int(oncq.max(initial=0)) > self.cell_cap:
+            if self._over_cell_cap(oncq):
                 return None
             cells_probed += int(oncq.sum())
             nbytes += self._add_grid_segs(self.o_img, self.outlier.row_ids,
@@ -746,10 +741,10 @@ class CoaxDevicePlan(_PlanBase):
             fhi = np.full((bp, rects.shape[1]), -np.inf, np.float32)
             flo[:b] = f32_ceil(rects[:, :, 0])
             fhi[:b] = f32_ceil(rects[:, :, 1])
-            segs.append({"rows": delta["rows_t"], "alive": delta["alive"],
-                         "flo": jnp.asarray(flo.T), "fhi": jnp.asarray(fhi.T)})
-            cfgs.append((min(DELTA_TILE, delta["m_pad"]), self.hit_cap,
-                         False, False, self.use_pallas, self.interpret, 0))
+            segs.append({"rows": delta["rows_l"], "alive": delta["alive"],
+                         "flo": jnp.asarray(flo), "fhi": jnp.asarray(fhi)})
+            cfgs.append((self.hit_cap, False, False, self.use_pallas,
+                         self.interpret, 0))
             ids_list.append(delta["ids"])
             out["qmaps"].append(None)
             out["bs"].append(b)
@@ -774,13 +769,16 @@ class CoaxDevicePlan(_PlanBase):
                     BatchStats(queries=b, backend="device"))
         seg_np = self._drain(ticket["res"], ticket["bs"])
         over = np.zeros(b, bool)
+        total = np.zeros(b, np.int64)          # exact device count per query
         rows_scanned = 0
         for (counts, _, scanned), qmap in zip(seg_np, ticket["qmaps"]):
             o = counts > self.hit_cap
             if qmap is None:
                 over |= o
+                total += counts
             else:
                 over[qmap[o]] = True
+                total[qmap] += counts
             rows_scanned += int(scanned.sum())
         parts_q, parts_r = [], []
         for (counts, hits, _), ids, qmap in zip(seg_np, ticket["ids"],
@@ -793,6 +791,7 @@ class CoaxDevicePlan(_PlanBase):
         if n_over:
             qsel = np.nonzero(over)[0]
             qo, ro, extra = self._reanswer(ticket, qsel)
+            _check_reanswer(total[qsel], qo)
             parts_q.append(qsel[qo])
             parts_r.append(ro)
             rows_scanned += extra

@@ -2,11 +2,11 @@
 
 Each kernel file contains the ``pl.pallas_call`` + BlockSpec tiling; ``ops``
 exposes padded jit'd wrappers; ``ref`` holds the pure-jnp oracles the tests
-compare against.  All kernels are validated in interpret mode on CPU; the
-BlockSpecs target TPU v5e VMEM/VPU/MXU geometry (DESIGN.md §3).  The
+compare against.  All kernels are validated in interpret mode on CPU.  The
 device-resident serving plane (``engine.device``, DESIGN.md §4) embeds
-``fused_scan`` — probe + segment search + filter + compaction in ONE launch
-with device-resident compacted hit buffers — as its per-wave program.
+``fused_scan`` — probe + segment search + filter in ONE launch, hits
+compacted in the same jitted program — as its per-wave program; it is the
+kernel that compiles for a TPU v5e (``tests/test_tpu_compile.py``).
 """
 from .fused_scan import fused_scan, fused_scan_call
 from .ops import (bucket_histogram, fused_range_scan, range_scan_batch_query,

@@ -17,10 +17,13 @@ VMEM: two (T, B) one-hots + the (B, B) accumulator; with T=256, B=128 that is
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ._platform import resolve_interpret
 
 DEFAULT_TILE = 256
 
@@ -77,7 +80,7 @@ def grid_histogram(
     *,
     buckets: int = 64,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Bucket-count the (x, d) sample onto a ``buckets x buckets`` grid."""
     n = x.shape[0]
@@ -95,6 +98,6 @@ def grid_histogram(
         ],
         out_specs=pl.BlockSpec((buckets, buckets), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((buckets, buckets), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x[None, :], d[None, :], params[None, :])
     return hist

@@ -15,10 +15,13 @@ paper's row-at-a-time ``primary.insert/outlier.insert``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ._platform import resolve_interpret
 
 DEFAULT_TILE = 1024
 
@@ -54,7 +57,7 @@ def margin_split(
     params: jax.Array,   # (8,) f32 — [m, b, eps_lb, eps_ub, n_valid, 0, 0, 0]
     *,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns ``(disp (N,), inlier_mask (N,) int32, tile_counts)``."""
     n = x.shape[0]
@@ -80,6 +83,6 @@ def margin_split(
             jax.ShapeDtypeStruct((1, n), jnp.int32),
             jax.ShapeDtypeStruct((1, num_tiles), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x[None, :], d[None, :], params[None, :])
     return disp[0], mask[0], counts[0]

@@ -13,14 +13,14 @@ standalone entry for tests and notebooks.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from . import ref
-from .fused_scan import fused_scan
+from .fused_scan import fused_scan, pad_to_lanes, padded_rows
 from .grid_histogram import grid_histogram
 from .margin_split import margin_split
 from .range_scan import range_scan
@@ -52,7 +52,7 @@ def range_scan_query(
     *,
     tile: int = 512,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Count + mask for one translated query (paper §6 scan).
 
@@ -85,7 +85,7 @@ def range_scan_batch_query(
     *,
     tile: int = 512,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Counts + masks for a BATCH of translated queries in one device launch.
 
@@ -124,38 +124,41 @@ def fused_range_scan(
     sv=None,               # (N,) in-cell sorted attribute (sort stage)
     tband=None,            # (B, 2) per-query [t_lo, t_hi) sort targets
     *,
-    tile: int = 512,
     hit_cap: int = 1024,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
-    """Standalone megakernel entry: pads N to a tile multiple and routes to
-    the Pallas kernel or the jnp oracle.
+    """Standalone megakernel entry: pads N to ``padded_rows(N)``, converts
+    every per-row plane to the kernel's lane layout, and routes to the
+    Pallas kernel or the jnp oracle.
 
-    Returns ``(counts (B,), hits (B, hit_cap + tile), scanned (B,))``; see
+    Returns ``(counts (B,), hits (B, hit_cap), scanned (B,))``; see
     ``fused_scan`` for the compacted-hits contract.  Positions ≥ the
     original N never appear (pads are dead: rows +inf, alive 0, coords -1).
     """
     rows_t = jnp.asarray(rows_t, jnp.float32)
     d, n = rows_t.shape
-    padded = _pad_to(rows_t, tile, jnp.inf)
+    n_pad = padded_rows(n)
+
+    def plane(x, fill, dtype):
+        return pad_to_lanes(jnp.asarray(x), n_pad, fill, dtype)
+
     if alive is None:
         alive = jnp.ones(n, jnp.int32)
-    alive_p = _pad_to(jnp.asarray(alive, jnp.int32), tile, 0)[None, :]
     kwargs = {}
     if coords is not None:
-        kwargs["coords"] = _pad_to(jnp.asarray(coords, jnp.int32), tile, -1)
+        kwargs["coords"] = plane(coords, -1, jnp.int32)
         kwargs["first"] = jnp.asarray(first, jnp.int32)
         kwargs["last"] = jnp.asarray(last, jnp.int32)
     if sv is not None:
-        kwargs["sv"] = _pad_to(jnp.asarray(sv, jnp.float32), tile, jnp.inf)[None, :]
+        kwargs["sv"] = plane(sv, jnp.inf, jnp.float32)
         kwargs["tband"] = jnp.asarray(tband, jnp.float32)
-    flo_t = jnp.asarray(rect_lo, jnp.float32).T
-    fhi_t = jnp.asarray(rect_hi, jnp.float32).T
     fn = fused_scan if use_pallas else ref.fused_scan_ref
     extra = {"interpret": interpret} if use_pallas else {}
-    counts, hits, scanned = fn(padded, flo_t, fhi_t, alive_p,
-                               tile=tile, hit_cap=hit_cap, **kwargs, **extra)
+    counts, hits, scanned = fn(
+        plane(rows_t, jnp.inf, jnp.float32),
+        jnp.asarray(rect_lo, jnp.float32), jnp.asarray(rect_hi, jnp.float32),
+        plane(alive, 0, jnp.int32), hit_cap=hit_cap, **kwargs, **extra)
     return counts[:, 0], hits, scanned[:, 0]
 
 
@@ -164,7 +167,7 @@ def bucket_histogram(
     buckets: int = 64,
     tile: int = 256,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Algorithm 1 bucket counts on the device; returns (B, B) float32."""
     x = jnp.asarray(x, jnp.float32)
@@ -189,7 +192,7 @@ def split_by_margin(
     x, d, m, b, eps_lb, eps_ub, *,
     tile: int = 1024,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused Alg.-1 split: returns ``(disp (N,), inlier_mask (N,) bool)``."""
     x = jnp.asarray(x, jnp.float32)

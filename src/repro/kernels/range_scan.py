@@ -21,10 +21,13 @@ operand, far under the ~16 MiB/core budget even with D=8 and double buffering.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ._platform import resolve_interpret
 
 DEFAULT_TILE = 512
 
@@ -66,7 +69,7 @@ def range_scan(
     window: jax.Array,      # (2,)  i32 — [scan_lo, scan_hi)
     *,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Evaluate one translated query over a record block.
 
@@ -95,6 +98,6 @@ def range_scan(
             jax.ShapeDtypeStruct((1, n), jnp.int32),
             jax.ShapeDtypeStruct((1, num_tiles), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows_t, rect_lo[:, None], rect_hi[:, None], window[None, :])
     return mask[0], counts[0]

@@ -23,10 +23,13 @@ entirely in the grid, not the block shapes.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ._platform import resolve_interpret
 
 DEFAULT_TILE = 512
 
@@ -65,7 +68,7 @@ def range_scan_batch(
     windows: jax.Array,     # (B, 2) i32 — per-query [scan_lo, scan_hi)
     *,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Evaluate B translated queries over one record block in one launch.
 
@@ -95,6 +98,6 @@ def range_scan_batch(
             jax.ShapeDtypeStruct((b, n), jnp.int32),
             jax.ShapeDtypeStruct((b, num_tiles), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows_t, rect_lo_t, rect_hi_t, windows)
     return mask, counts

@@ -2,9 +2,8 @@
 
 Each ``*_ref`` mirrors its kernel's exact contract (same inputs incl. padding
 and params vectors, same outputs) so the tests can ``assert_allclose`` across
-shape/dtype sweeps, and doubles as the CPU fallback path — notably
-``range_scan_batch_ref`` is the CPU filter stage of the device serving
-plane's fused per-wave program (``engine.device``, DESIGN.md §4).
+shape/dtype sweeps — and ``fused_scan_ref`` is the CPU route of the device
+serving plane's per-wave program (``engine.device``, DESIGN.md §4).
 """
 from __future__ import annotations
 
@@ -45,64 +44,65 @@ def range_scan_batch_ref(rows_t, rect_lo_t, rect_hi_t, windows, *, tile: int = 5
     return mask, counts
 
 
-def fused_scan_ref(rows_t, flo_t, fhi_t, alive, coords=None, first=None,
+def fused_scan_ref(rows, flo, fhi, alive, coords=None, first=None,
                    last=None, sv=None, tband=None, gidx=None, *,
-                   tile: int = 512, hit_cap: int = 1024):
-    """Oracle for ``fused_scan.fused_scan`` — identical contract, and the
-    CPU fast path of the §4 device plane.
+                   hit_cap: int = 1024):
+    """Oracle for ``fused_scan.fused_scan`` — identical contract (the same
+    lane-layout planes, ``fused_scan.to_lanes``), and the CPU route of the
+    §4 device plane.
 
-    Returns ``(counts (Bp, 1) i32, hits (Bp, hit_cap + tile) i32,
-    scanned (Bp, 1) i32)`` with ``hits[b, :min(counts[b], hit_cap)]`` the
-    matching row positions ascending (unspecified slots are -1, which also
-    matches the kernel for non-overflowing queries).
+    Returns ``(counts (Bp, 1) i32, hits (Bp, hit_cap) i32, scanned (Bp, 1)
+    i32)`` with ``hits[b, :min(counts[b], hit_cap)]`` the matching logical
+    row positions ascending and ``-1`` after.
 
-    Two things differ from the kernel's tile loop, neither observable:
+    Two things differ from the kernel, neither observable:
 
-    * **Candidate-gather scan** (``gidx (Bp, R)`` i32): each query's
-      predicate evaluation runs over only ``rows_t[:, gidx[b]]`` — the
-      device plane fills ``gidx`` with EXACTLY each query's probe-derived
-      candidate-box row positions, ascending (each cell in the candidate
-      coord box is a contiguous cell-major block), padded with the
-      position of a dead ``+inf`` pad row.  Exact because every row a
-      query can HIT is a member of its candidate box (rows outside fail
-      the coord test in the full scan too), each candidate appears exactly
-      once, and pad slots fail the ``alive`` test.  Because membership is
-      exact, the ``coords``/``first``/``last`` test is implied and skipped
-      on this path (same ``counts``/``hits``/``scanned``).  Hit positions
-      come back global via a ``gidx`` gather.  This makes the CPU oracle
-      scale with per-query candidate counts instead of table size, like
-      the numpy path; ``gidx=None`` scans the full array
-      (kernel-identical shape work).
-    * **Bisect compaction**: instead of the kernel's per-tile
-      cumsum-scatter (XLA CPU scatters serialise), the j-th defined hit
-      slot is located by bisecting the running hit count — same prefix,
-      built by pure gathers.
+    * **Candidate-gather scan** (``gidx (Bp, R)`` i32 logical positions):
+      each query's predicate evaluation runs over only its ``gidx`` rows —
+      the device plane fills ``gidx`` with EXACTLY each query's
+      probe-derived candidate-box row positions, ascending (each cell in
+      the candidate coord box is a contiguous cell-major block), padded
+      with the position of a dead ``+inf`` pad row.  Exact because every
+      row a query can HIT is a member of its candidate box, each candidate
+      appears exactly once, and pad slots fail the ``alive`` test.  The
+      ``coords``/``first``/``last`` test is implied and skipped on this
+      path.  This makes the CPU oracle scale with per-query candidate
+      counts instead of table size; ``gidx=None`` scans the full array.
+    * **Bisect compaction** over the running hit count instead of the
+      kernel's bitmap words — same prefix.
     """
-    d, n = rows_t.shape
-    bp = flo_t.shape[1]
+    from .fused_scan import from_lanes, lane_index
+
+    d = rows.shape[0]
+    bp = flo.shape[0]
     if gidx is not None:
-        width = gidx.shape[1]
-        inside = jnp.ones((bp, width), bool)
+        at = lane_index(gidx)
+        flat = rows.reshape(d, -1)
+        inside = jnp.ones(gidx.shape, bool)
         for j in range(d):
-            inside &= (rows_t[j][gidx] >= flo_t[j][:, None]) & (
-                rows_t[j][gidx] < fhi_t[j][:, None])
-        cand = alive[0][gidx] > 0
+            inside &= (flat[j][at] >= flo[:, j:j + 1]) & (
+                flat[j][at] < fhi[:, j:j + 1])
+        cand = alive.reshape(-1)[at] > 0
         # coord-box membership is implied: gidx holds exactly the box rows
         if sv is not None:
-            cand = cand & (sv[0][gidx] >= tband[:, :1]) & (
-                sv[0][gidx] < tband[:, 1:])
+            v = sv.reshape(-1)[at]
+            cand = cand & (v >= tband[:, :1]) & (v < tband[:, 1:])
     else:
+        rows_t = from_lanes(rows)
+        n = rows_t.shape[1]
         inside = jnp.ones((bp, n), bool)
         for j in range(d):
-            inside &= (rows_t[j][None, :] >= flo_t[j][:, None]) & (
-                rows_t[j][None, :] < fhi_t[j][:, None])
-        cand = jnp.broadcast_to(alive > 0, (bp, n))
+            inside &= (rows_t[j][None, :] >= flo[:, j:j + 1]) & (
+                rows_t[j][None, :] < fhi[:, j:j + 1])
+        cand = jnp.broadcast_to(from_lanes(alive)[None, :] > 0, (bp, n))
         if coords is not None:
-            for j in range(coords.shape[0]):
-                cand = cand & (coords[j][None, :] >= first[:, j:j + 1]) & (
-                    coords[j][None, :] <= last[:, j:j + 1])
+            cl = from_lanes(coords)
+            for j in range(cl.shape[0]):
+                cand = cand & (cl[j][None, :] >= first[:, j:j + 1]) & (
+                    cl[j][None, :] <= last[:, j:j + 1])
         if sv is not None:
-            cand = cand & (sv >= tband[:, :1]) & (sv < tband[:, 1:])
+            v = from_lanes(sv)[None, :]
+            cand = cand & (v >= tband[:, :1]) & (v < tband[:, 1:])
     hit = cand & inside
 
     running = jnp.cumsum(hit.astype(jnp.int32), axis=1)        # nondecreasing
@@ -117,8 +117,7 @@ def fused_scan_ref(rows_t, flo_t, fhi_t, alive, coords=None, first=None,
             gidx, jnp.minimum(idx, gidx.shape[1] - 1), axis=1)
     else:
         pos = idx
-    body = jnp.where(defined, pos.astype(jnp.int32), -1)
-    hits = jnp.pad(body, ((0, 0), (0, tile)), constant_values=-1)
+    hits = jnp.where(defined, pos.astype(jnp.int32), -1)
     return counts, hits, scanned
 
 

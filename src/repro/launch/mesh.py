@@ -2,30 +2,19 @@
 
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — required for the smoke tests, which must see
-exactly one device.
-
-``make_mesh_compat`` papers over the ``axis_types`` API gap: jax >= 0.5 wants
-explicit ``jax.sharding.AxisType.Auto`` axis types, jax 0.4.x (the pinned
-version) predates both the kwarg and the enum.  Every mesh in this repo (and
-in the subprocess-driven distribution tests) is Auto-typed, which is exactly
-the older versions' only behaviour, so falling back to a plain ``make_mesh``
-is semantics-preserving.
+exactly one device.  Every mesh in this repo is Auto-typed.
 """
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh", "make_mesh_compat"]
+__all__ = ["make_production_mesh", "make_local_mesh", "make_mesh"]
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported, without
-    where not (jax 0.4.x lacks the kwarg and ``jax.sharding.AxisType``)."""
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -37,9 +26,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (fake or real) local devices exist."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -1,13 +1,13 @@
 """Device profiling hooks (DESIGN.md §10.2, profiling layer).
 
-``profile(logdir)`` gates an optional ``jax.profiler`` trace capture
-around a block of device waves — XLA compile/execute timelines land in
-``logdir`` for TensorBoard / Perfetto.  The context is a strict no-op
-(and never raises) when jax is absent, the profiler is unavailable, or
-a capture is already active, so call sites can wrap hot paths
-unconditionally.  The cheap per-wave counters (``transfer_bytes``,
-``dispatches``, ``compile_count``) do NOT live here — they fold into
-the metrics registry from the device plan itself (§10.1).
+``profile(logdir)`` wraps a ``jax.profiler`` trace capture around a block
+of device waves — XLA compile/execute timelines land in ``logdir`` for
+TensorBoard / Perfetto.  A capture that was asked for and cannot start or
+stop raises: a measurement never runs on without its trace.  A nested or
+concurrent entry yields ``False`` — the outer capture already covers the
+block.  The cheap per-wave counters (``transfer_bytes``, ``dispatches``,
+``compile_count``) do NOT live here — they fold into the metrics registry
+from the device plan itself (§10.1).
 """
 from __future__ import annotations
 
@@ -24,29 +24,20 @@ _active = threading.Lock()    # one capture at a time, process-wide
 def profile(logdir: Optional[str], enabled: bool = True) -> Iterator[bool]:
     """Capture a ``jax.profiler`` trace into ``logdir`` over the block.
 
-    Yields True when a capture actually started (jax importable, no
-    other capture running, ``enabled`` and ``logdir`` truthy), False
-    otherwise — callers may branch on it but never need to."""
+    Yields True when this call started the capture, False when
+    ``enabled``/``logdir`` are falsy or an outer capture is running."""
     if not enabled or not logdir:
         yield False
         return
     if not _active.acquire(blocking=False):
         yield False                       # nested/concurrent: outer wins
         return
-    started = False
+    import jax                            # first use only: keeps obs jax-free
     try:
+        jax.profiler.start_trace(logdir)
         try:
-            import jax
-            jax.profiler.start_trace(logdir)
-            started = True
-        except Exception:                 # pragma: no cover - no jax
-            pass
-        yield started
+            yield True
+        finally:
+            jax.profiler.stop_trace()
     finally:
-        if started:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:             # pragma: no cover
-                pass
         _active.release()

@@ -19,7 +19,7 @@ import weakref
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import COAXIndex, CoaxConfig
 from repro.data import make_airline, make_generic_fd, make_osm
 from repro.engine import QueryServer, SemanticCache, ShardedCOAX

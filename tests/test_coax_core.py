@@ -3,7 +3,7 @@ on every engine, margins/grouping reproduce Table 1, translation (Eq. 2)
 over-approximates but never loses results."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st  # hypothesis or skip-fallback
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     COAXIndex,

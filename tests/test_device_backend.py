@@ -56,7 +56,7 @@ def test_device_pallas_kernel_path():
     rects[0] = full_rect(3)
     gf = GridFile(data, index_dims=[0, 1, 2], cells_per_dim=4, sort_dim=1,
                   backend="device",
-                  device_opts={"use_pallas": True, "interpret": True, "tile": 256})
+                  device_opts={"use_pallas": True, "interpret": True})
     q_d, r_d = gf.query_batch(rects, rects)
     gf.backend = "numpy"
     q_n, r_n = gf.query_batch(rects, rects)
@@ -211,7 +211,7 @@ def test_fused_kernel_interpret_parity():
     import jax.numpy as jnp
 
     rng = np.random.default_rng(21)
-    n, d, b, tile, cap = 700, 3, 5, 256, 64
+    n, d, b, cap = 700, 3, 5, 64
     rows_t = rng.normal(0, 10, (d, n)).astype(np.float32)
     lo = rng.uniform(-15, 0, (b, d)).astype(np.float32)
     hi = lo + rng.uniform(0, 20, (b, d)).astype(np.float32)
@@ -228,7 +228,7 @@ def test_fused_kernel_interpret_parity():
                    "sv": sv, "tband": tband}]
     for stages in stage_sets:
         outs = [fused_range_scan(rows_t, lo, hi, alive, **stages,
-                                 tile=tile, hit_cap=cap, use_pallas=up)
+                                 hit_cap=cap, use_pallas=up)
                 for up in (True, False)]
         for (c_a, h_a, s_a), (c_b, h_b, s_b) in zip(outs, outs[1:]):
             assert np.array_equal(c_a, c_b), stages.keys()
@@ -263,7 +263,7 @@ def test_fused_kernel_interpret_parity():
                      constant_values=jnp.inf)
     _, ref_counts = kref.range_scan_batch_ref(
         padded, jnp.asarray(lo).T, jnp.asarray(hi).T, win, tile=256)
-    c0, _, _ = fused_range_scan(rows_t, lo, hi, tile=tile, hit_cap=cap,
+    c0, _, _ = fused_range_scan(rows_t, lo, hi, hit_cap=cap,
                                 use_pallas=True)
     assert np.array_equal(np.asarray(c0), np.asarray(ref_counts.sum(axis=1)))
 
@@ -276,10 +276,11 @@ def test_gather_oracle_matches_full_scan():
     box cell, pad slots pointing at a dead pad row)."""
     from repro.engine.device import _multi_arange
     from repro.kernels import ref as kref
+    from repro.kernels.fused_scan import pad_to_lanes, padded_rows
 
     rng = np.random.default_rng(33)
     n, d, b, k, c = 2_040, 3, 7, 2, 4
-    tile, cap = 256, 64
+    cap = 64
     # cell-major layout: rows sorted by linear cell id, like a GridFile,
     # then one dead +inf pad row for the gather lists to point at
     cell = np.sort(rng.integers(0, c ** k, n))
@@ -310,11 +311,16 @@ def test_gather_oracle_matches_full_scan():
     for q, lst in enumerate(lists):
         gidx[q, :lst.size] = lst
 
-    full = kref.fused_scan_ref(rows_t, lo.T, hi.T, alive[None], coords,
-                               first, last, tile=tile, hit_cap=cap)
-    gath = kref.fused_scan_ref(rows_t, lo.T, hi.T, alive[None], coords,
+    # the kernel's lane layout, dead-padded to its grid size
+    n_pad = padded_rows(n + 1)
+    rows_l = pad_to_lanes(rows_t, n_pad, np.inf, np.float32)
+    coords_l = pad_to_lanes(coords, n_pad, -1, np.int32)
+    alive_l = pad_to_lanes(alive, n_pad, 0, np.int32)
+    full = kref.fused_scan_ref(rows_l, lo, hi, alive_l, coords_l,
+                               first, last, hit_cap=cap)
+    gath = kref.fused_scan_ref(rows_l, lo, hi, alive_l, coords_l,
                                first, last, gidx=np.asarray(gidx),
-                               tile=tile, hit_cap=cap)
+                               hit_cap=cap)
     c_f, h_f, s_f = (np.asarray(x) for x in full)
     c_g, h_g, s_g = (np.asarray(x) for x in gath)
     assert np.array_equal(c_f, c_g)

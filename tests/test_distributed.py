@@ -2,12 +2,8 @@
 equivalence, pipeline parallelism, gradient compression, HLO parsing.
 
 Multi-device cases run in subprocesses with fake XLA devices so the main
-test process keeps exactly one device (per the brief).  Mesh construction
-goes through ``make_mesh_compat`` so the cases run on the pinned jax 0.4.x
-(no ``axis_types`` kwarg, no ``jax.set_mesh``) as well as newer versions;
-the only version gate left is ``jax.make_mesh`` itself (added in 0.4.35),
-expressed as a skip — never an ``xfail(strict=False)``, whose silent
-pass/fail flapping can hide regressions."""
+test process keeps exactly one device.  Mesh construction goes through
+``launch.mesh.make_mesh`` (Auto axis types)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,18 +16,12 @@ from repro.distributed.compression import (Int8Compressor, TopKCompressor,
 from repro.distributed.partitioning import logical_to_spec, use_rules
 from repro.launch.hloparse import collective_bytes
 
-requires_make_mesh = pytest.mark.skipif(
-    not hasattr(jax, "make_mesh"),
-    reason=f"jax.make_mesh absent in jax {jax.__version__} (needs >= 0.4.35)")
-
-
 def test_main_process_single_device():
     assert len(jax.devices()) == 1
 
 
 # --------------------------- sharding rules ------------------------------ #
 
-@requires_make_mesh
 def test_rules_divisibility_adaptation():
     code = """
 import jax
@@ -66,7 +56,6 @@ print("RULES_OK")
     assert "RULES_OK" in out
 
 
-@requires_make_mesh
 def test_tiny_batch_falls_back_to_context_parallel_decode():
     code = """
 from repro.launch.mesh import make_local_mesh
@@ -81,7 +70,6 @@ print("CP_OK")
     assert "CP_OK" in run_in_subprocess(code, devices=8)
 
 
-@requires_make_mesh
 def test_sharded_step_matches_single_device():
     """The same train step on a 2x2 mesh must produce the same loss as on a
     single device — GSPMD must not change the math.  ``in_shardings`` take
@@ -130,12 +118,11 @@ print("SHARDED_OK", d)
     assert "SHARDED_OK" in run_in_subprocess(code, devices=4)
 
 
-@requires_make_mesh
 def test_pipeline_parallel_forward_matches_sequential():
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import pipeline_forward, bubble_fraction
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
 assert abs(bubble_fraction(4, 12) - 3/15) < 1e-12
 
@@ -151,7 +138,7 @@ ref = x
 for s in range(n_stages):
     ref = stage_fn(ws[s], ref)
 
-mesh = make_mesh_compat((n_stages,), ("stage",))
+mesh = make_mesh((n_stages,), ("stage",))
 out = pipeline_forward(stage_fn, ws, x, mesh, n_microbatches=4)
 err = float(jnp.abs(out - ref).max())
 assert err < 1e-5, err
@@ -160,7 +147,6 @@ print("PIPELINE_OK", err)
     assert "PIPELINE_OK" in run_in_subprocess(code, devices=4)
 
 
-@requires_make_mesh
 def test_compressed_psum_close_to_exact():
     code = """
 import jax, jax.numpy as jnp, numpy as np
@@ -168,9 +154,9 @@ from functools import partial
 from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import compressed_psum
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
-mesh = make_mesh_compat((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(0, 1, (4, 256)), jnp.float32)
 

@@ -10,15 +10,14 @@ Every backend's bit-identity argument bottoms out in two facts:
   so the numpy, device and sharded planes all navigate from the same
   nav-rects.
 
-Hypothesis (via ``_hypothesis_compat``: skipped, not errored, when absent)
-drives both over adversarial floats — ±inf, subnormals, f32-overflowing
-magnitudes — alongside deterministic spot checks of the same corners that
-run even without hypothesis.
+Hypothesis drives both over adversarial floats — ±inf, subnormals,
+f32-overflowing magnitudes — alongside deterministic spot checks of the
+same corners.
 """
 import numpy as np
 import pytest
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import COAXIndex, translate_rect, translate_rects
 from repro.core.gridfile import f32_ceil
 from repro.data import make_generic_fd
@@ -147,9 +146,11 @@ def test_translate_rects_matches_scalar_on_adversarial_floats(bounds):
     _check_translate_agreement(np.stack([other, rect, other]))
 
 
-@pytest.mark.skipif(not HAVE_HYPOTHESIS, reason="hypothesis not installed")
 def test_hypothesis_is_driving():
-    """Guard: when hypothesis IS available the @given tests above must be
-    real property tests, not silently inert decorators."""
+    """Guard: the @given tests above must be real property tests, not
+    silently inert decorators."""
     from hypothesis import given as real_given
     assert given is real_given
+    assert hasattr(
+        test_translate_rects_matches_scalar_on_adversarial_floats,
+        "hypothesis")

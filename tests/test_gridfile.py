@@ -1,7 +1,7 @@
 """Grid-file and helper invariants (paper §6)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st  # hypothesis or skip-fallback
+from hypothesis import given, settings, strategies as st
 
 from repro.core import FullScan, GridFile, fit_cells_per_dim, gather_ranges
 from repro.core.types import full_rect, rect_contains

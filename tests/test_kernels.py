@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st  # hypothesis or skip-fallback
+from hypothesis import given, settings, strategies as st
 from numpy.testing import assert_allclose
 
 from repro.kernels import bucket_histogram, range_scan_query, split_by_margin

@@ -32,7 +32,7 @@ from repro.data import make_generic_fd
 from repro.engine import ShardedCOAX
 from repro.storage import Durability, restore
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from workloads import assert_equiv, rects_for, violate_fd
 
 _DS = make_generic_fd(9_000, 5, ((0, 1), (2, 3)), seed=7)
@@ -49,17 +49,6 @@ BG = CoaxConfig(compact_min_delta=300, compact_delta_frac=0.01,
 SYNC = CoaxConfig(compact_min_delta=300, compact_delta_frac=0.01,
                   drift_min_delta=200, compact_check_rows=64,
                   delta_l0_spill=64, background_compact=False)
-
-
-def _device_ok():
-    try:
-        from repro.engine import device_available
-        return device_available()
-    except ImportError:
-        return False
-
-
-needs_device = pytest.mark.skipif(not _device_ok(), reason="jax unavailable")
 
 
 def _hold_window_open(idx):
@@ -215,7 +204,7 @@ def _write_until_build_starts(idx, seed0=500, batch=120):
 
 
 @pytest.mark.parametrize("backend", [
-    "numpy", pytest.param("device", marks=needs_device)])
+    "numpy", "device"])
 def test_queries_exact_during_background_build(backend):
     dev = backend == "device"
     idx = COAXIndex(_DS.data, BG)
@@ -355,6 +344,7 @@ def test_crash_mid_handoff_recovers_bit_identical(tmp_path):
         rows = _more(2_000 + j, 120)
         rec.insert(rows)
         oracle.insert(rows)
+    rec.finish_handoff()          # a build those writes fired may still run
     assert rec.epoch == oracle.epoch
     assert rec.trigger_checks == oracle.trigger_checks
     q, r = rec.query_batch(rects)
@@ -392,20 +382,21 @@ def test_background_world_converges_with_sync_world():
 # --------------------------------------------------------------------- #
 # Device plane: pow2 image bucketing keeps the jit cache flat
 # --------------------------------------------------------------------- #
-@needs_device
 def test_grid_image_pow2_padding():
     from repro.engine.device import _GridImage, _next_pow2
+    from repro.kernels.fused_scan import GROUP_ROWS, padded_rows
 
-    idx = COAXIndex(_DS.data[:3_000], CoaxConfig(auto_compact=False))
-    for tile in (256, 512):
-        img = _GridImage(idx.primary, tile)
+    for n_rows in (3_000, 9_000):
+        idx = COAXIndex(_DS.data[:n_rows], CoaxConfig(auto_compact=False))
+        img = _GridImage(idx.primary)
         n = idx.primary.n_rows
         assert img.n_pad >= n + 1                # the dead +inf pad row
-        assert img.n_pad % tile == 0
-        assert img.n_pad == max(tile, _next_pow2(n + 1))
+        assert img.n_pad == padded_rows(img.n_pad)   # a kernel grid size
+        assert img.n_pad == max(GROUP_ROWS, _next_pow2(n + 1))
+        assert img.rows.shape == (idx.primary.rows.shape[1],
+                                  img.n_pad // 128, 128)
 
 
-@needs_device
 def test_compile_count_flat_across_compaction_epochs():
     cfg = CoaxConfig(compact_min_delta=300, compact_delta_frac=0.01,
                      drift_min_delta=10**9, compact_check_rows=64,

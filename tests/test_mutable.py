@@ -15,7 +15,7 @@ import copy
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import COAXIndex, CoaxConfig, DeltaPlane, FullScan, full_rect
 from repro.data import make_airline, make_generic_fd
 from repro.engine import BatchQueryExecutor, QueryServer

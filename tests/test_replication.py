@@ -36,7 +36,7 @@ from repro.storage import (WalFrameCursor, WriteAheadLog, read_wal, restore,
                            wal_path)
 from repro.storage.wal import _FILE_HDR, _REC_HDR, OP_INSERT
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 from workloads import fullscan_expected, mutable_workloads, rects_for, violate_fd
 
 # compaction triggers low enough that the schedules below cross them

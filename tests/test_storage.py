@@ -24,7 +24,7 @@ from repro.storage import (WriteAheadLog, atomic, latest_snapshot,
                            read_manifest, read_wal, restore, wal_path,
                            write_snapshot)
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 from workloads import (NOAUTO, fullscan_expected, mutable_workloads,
                        rects_for, violate_fd)
 
@@ -88,14 +88,6 @@ def _assert_trackers_equal(live, rec, tag=""):
                               rec._fd_trackers[k].xty), (tag, k, "xty")
     assert live._x_scale == rec._x_scale, (tag, "x_scale")
     assert live.drift_predictability() == rec.drift_predictability(), tag
-
-
-def _device_ok():
-    try:
-        from repro.engine import device_available
-        return device_available()
-    except ImportError:
-        return False
 
 
 # --------------------------------------------------------------------- #
@@ -263,7 +255,6 @@ def test_kill_and_recover_matrix(tmp_path, wname, shards):
         if live.compactions != before:
             compact_ops.append(i)
     assert compact_ops, "schedule must cross the compaction trigger"
-    check_device = _device_ok()
 
     # crash points: start, pre-first-compaction, right at it, and the end
     points = sorted({0, max(compact_ops[0] - 1, 0), compact_ops[0] + 1,
@@ -282,11 +273,10 @@ def test_kill_and_recover_matrix(tmp_path, wname, shards):
             _apply(rec, op)
         _assert_state_equal(live, rec, rects, (wname, shards, crash_at))
         _assert_trackers_equal(live, rec, (wname, shards, crash_at))
-        if check_device:
-            lq, lr = _flat_hits(live, rects, backend="device")
-            q, r = _flat_hits(rec, rects, backend="device")
-            assert np.array_equal(q, lq) and np.array_equal(r, lr), \
-                (wname, shards, crash_at, "device")
+        lq, lr = _flat_hits(live, rects, backend="device")
+        q, r = _flat_hits(rec, rects, backend="device")
+        assert np.array_equal(q, lq) and np.array_equal(r, lr), \
+            (wname, shards, crash_at, "device")
 
 
 def test_recover_preserves_compaction_schedule(tmp_path):
@@ -654,40 +644,3 @@ def test_recovery_equals_uninterrupted_property(tmp_path_factory, data):
     _assert_state_equal(live, rec, rects, ("prop", crash_at))
     _assert_trackers_equal(live, rec, ("prop", crash_at))
 
-
-if not HAVE_HYPOTHESIS:
-    # emulated draws: the property still runs on minimal CI images
-    def test_recovery_property_emulated(tmp_path):
-        name, ds, more = _dataset()
-        rng = np.random.default_rng(0)
-        cfg = CoaxConfig(compact_min_delta=150, compact_delta_frac=0.01,
-                         drift_min_delta=100)
-        rects = rects_for(ds.data, n=4, seed=1)
-        for trial in range(5):
-            n_ops = int(rng.integers(1, 9))
-            ops = []
-            for i in range(n_ops):
-                kind = rng.choice(["ins", "ins_bad", "del"])
-                if kind == "del":
-                    lo = int(rng.integers(0, 2400))
-                    ops.append(("delete", np.arange(lo, lo + 40)))
-                else:
-                    rows = more(int(rng.integers(50, 80)), 60)
-                    if kind == "ins_bad":
-                        rows = violate_fd(ds, rows)
-                    ops.append(("insert", rows))
-            crash_at = int(rng.integers(0, n_ops + 1))
-            live = COAXIndex(ds.data, cfg)
-            for op in ops:
-                _apply(live, op)
-            d = tmp_path / f"trial{trial}"
-            vic = COAXIndex(ds.data, cfg).attach_durability(d)
-            for op in ops[:crash_at]:
-                _apply(vic, op)
-            vic.durable.sync()
-            del vic
-            rec = restore(d, durable=True)
-            for op in ops[crash_at:]:
-                _apply(rec, op)
-            _assert_state_equal(live, rec, rects, ("emul", trial))
-            _assert_trackers_equal(live, rec, ("emul", trial))

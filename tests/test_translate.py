@@ -1,6 +1,6 @@
 """Query translation (Eq. 2) unit + property tests."""
 import numpy as np
-from _hypothesis_compat import given, settings, st  # hypothesis or skip-fallback
+from hypothesis import given, settings, strategies as st
 
 from repro.core import LinearModel, translate_dependent_interval, translate_rect
 from repro.core.types import FDGroup, full_rect
