@@ -438,21 +438,24 @@ class _PlanBase:
         ``bs`` is the real (un-padded) query count per segment.  Returns
         per-segment ``(counts (b,), hits (bp, W), scanned (b,))``.  The
         ``device.transfer`` span covers the ``block_until_ready`` fence
-        plus the d2h copies — execute+transfer time, distinct from the
-        dispatch span's compile+launch (DESIGN.md §10.2)."""
+        (``device.wait``: the wave's execution) plus the d2h copies
+        (``device.copy``), distinct from the dispatch span's compile+launch
+        (DESIGN.md §10.2)."""
         t0 = time.perf_counter()
         d2h = 0
-        with obs.span("device.transfer") as sp:
-            res = jax.block_until_ready(res)
-            out = []
-            for (counts, hits, scanned), b in zip(res, bs):
-                counts = np.asarray(counts)[:b, 0]
-                hits = np.asarray(hits)
-                scanned = np.asarray(scanned)[:b, 0]
-                d2h += counts.nbytes + hits.nbytes + scanned.nbytes
-                out.append((counts, hits, scanned))
-            if sp is not None:
-                sp.args["bytes_d2h"] = d2h
+        with obs.span("device.transfer"):
+            with obs.span("device.wait"):
+                res = jax.block_until_ready(res)
+            with obs.span("device.copy") as sp:
+                out = []
+                for (counts, hits, scanned), b in zip(res, bs):
+                    counts = np.asarray(counts)[:b, 0]
+                    hits = np.asarray(hits)
+                    scanned = np.asarray(scanned)[:b, 0]
+                    d2h += counts.nbytes + hits.nbytes + scanned.nbytes
+                    out.append((counts, hits, scanned))
+                if sp is not None:
+                    sp.args["bytes_d2h"] = d2h
         self.bytes_d2h += d2h
         obs.get_registry().counter(
             "coax_device_bytes", "bytes moved across the PCIe/ICI boundary",
@@ -505,21 +508,25 @@ class DevicePlan(_PlanBase):
         b = nav_rects.shape[0]
         if b == 0 or self.n_rows == 0:
             return {"b": b, "res": None}
-        first, last, n_cells_q = self._img.probe_batch(nav_rects)
+        with obs.span("device.probe"):
+            first, last, n_cells_q = self._img.probe_batch(nav_rects)
         if self._over_cell_cap(n_cells_q):
             return None
         bp = self.bucket(b)
-        glists, gw = None, 0
-        if not self.use_pallas:
-            glists = self._img.candidate_lists(first, last, n_cells_q)
-            gw = self._img.gather_bucket(glists)
-        seg, nbytes = self._img.seg_inputs(nav_rects, filter_rects,
-                                           first, last, bp,
-                                           glists=glists, gw=gw)
+        with obs.span("device.inputs") as sp:
+            glists, gw = None, 0
+            if not self.use_pallas:
+                glists = self._img.candidate_lists(first, last, n_cells_q)
+                gw = self._img.gather_bucket(glists)
+            seg, nbytes = self._img.seg_inputs(nav_rects, filter_rects,
+                                               first, last, bp,
+                                               glists=glists, gw=gw)
+            self._count_h2d(nbytes)
+            if sp is not None:
+                sp.args["bytes_h2d"] = nbytes
         cfg = self._img.config_for(self.hit_cap, self.use_pallas,
                                    self.interpret, gw)
         res = self._dispatch([seg], [cfg])
-        self._count_h2d(nbytes)
         return {"b": b, "res": res, "cells": int(n_cells_q.sum()),
                 "nav": nav_rects, "filt": filter_rects}
 
@@ -537,9 +544,10 @@ class DevicePlan(_PlanBase):
         rows_scanned = int(scanned.sum())
         if over.any():                # exact per-query host re-answer (§4)
             qsel = np.nonzero(over)[0]
-            qo, ro = self.grid._query_batch_numpy(
-                ticket["nav"][qsel], ticket["filt"][qsel])
-            _check_reanswer(counts[qsel], qo)
+            with obs.span("device.reanswer", queries=int(qsel.size)):
+                qo, ro = self.grid._query_batch_numpy(
+                    ticket["nav"][qsel], ticket["filt"][qsel])
+                _check_reanswer(counts[qsel], qo)
             rows_scanned += self.grid.last_batch_stats.rows_scanned
             out_q = np.concatenate([out_q, qsel[qo]])
             out_r = np.concatenate([out_r, ro])
@@ -603,16 +611,18 @@ class CoaxDevicePlan(_PlanBase):
         self.p_img = self.o_img = self._delta = None
 
     # ------------------------------------------------------------------ #
-    def _refresh_writes(self) -> None:
+    def _refresh_writes(self) -> int:
         """Re-upload liveness masks / the delta image iff the delta-plane
-        counters moved since the last wave (cheap no-op in steady state)."""
+        counters moved since the last wave (cheap no-op in steady state).
+        Returns the bytes uploaded."""
         dp, do = self.index.delta_primary, self.index.delta_outlier
         dead_key = (dp.n_tombstones, do.n_tombstones)
+        nbytes = 0
         if dead_key != self._dead_key:
             self._dead_host = self.index._dead_ids()
             for img in (self.p_img, self.o_img):
                 if img is not None:
-                    self._count_h2d(img.set_alive(self._dead_host))
+                    nbytes += img.set_alive(self._dead_host)
             self._dead_key = dead_key
         delta_key = (dp.n_log, dp.n_log_dead, do.n_log, do.n_log_dead)
         if delta_key != self._delta_key:
@@ -628,10 +638,13 @@ class CoaxDevicePlan(_PlanBase):
                 self._delta = {"rows_l": jnp.asarray(rows_l),
                                "alive": jnp.asarray(alive),
                                "rows": rows, "ids": ids}
-                self._count_h2d(rows_l.nbytes + alive.nbytes)
+                nbytes += rows_l.nbytes + alive.nbytes
             else:
                 self._delta = None
             self._delta_key = delta_key
+        if nbytes:
+            self._count_h2d(nbytes)
+        return nbytes
 
     # ------------------------------------------------------------------ #
     def _add_grid_segs(self, img, ids, nav, filt, first, last, ncq,
@@ -701,57 +714,65 @@ class CoaxDevicePlan(_PlanBase):
         b = rects.shape[0]
         if b == 0:
             return {"b": 0, "res": None}
-        self._refresh_writes()
+        probe = outlier_probe = None
+        with obs.span("device.probe"):
+            if self.p_img is not None:
+                probe = self.p_img.probe_batch(nav_rects)
+            # §8.2.3 bbox skip: non-touch queries go in inert, not
+            # sub-batched — same result (no outlier row can pass their
+            # predicate), fixed shape
+            touch = np.zeros(b, bool)
+            if self.index._outlier_lo is not None:
+                touch = np.all(
+                    (rects[:, :, 0] <= self.index._outlier_hi)
+                    & (rects[:, :, 1] > self.index._outlier_lo), axis=1)
+            if self.o_img is not None and touch.any():
+                # nav == full rect for the full-dim outlier grid
+                of, ol, oncq = self.o_img.probe_batch(rects)
+                outlier_probe = (of, ol, np.where(touch, oncq, 0))
+        cells_probed = 0
+        for p in (probe, outlier_probe):
+            if p is not None:
+                if self._over_cell_cap(p[2]):
+                    return None
+                cells_probed += int(p[2].sum())
+
         bp = self.bucket(b)
         out = {"segs": [], "cfgs": [], "ids": [], "qmaps": [], "bs": []}
-        cells_probed = 0
-        nbytes = 0
+        with obs.span("device.inputs") as sp:
+            nbytes = 0
+            up = self._refresh_writes()
+            if probe is not None:
+                nbytes += self._add_grid_segs(
+                    self.p_img, self.primary.row_ids, nav_rects, rects,
+                    *probe, bp, out)
+            if outlier_probe is not None:
+                nbytes += self._add_grid_segs(
+                    self.o_img, self.outlier.row_ids, rects, rects,
+                    *outlier_probe, bp, out, qmask=touch)
+            segs, cfgs, ids_list = out["segs"], out["cfgs"], out["ids"]
 
-        if self.p_img is not None:
-            first, last, ncq = self.p_img.probe_batch(nav_rects)
-            if self._over_cell_cap(ncq):
-                return None
-            cells_probed += int(ncq.sum())
-            nbytes += self._add_grid_segs(self.p_img, self.primary.row_ids,
-                                          nav_rects, rects, first, last,
-                                          ncq, bp, out)
-
-        # §8.2.3 bbox skip: non-touch queries go in inert, not sub-batched —
-        # same result (no outlier row can pass their predicate), fixed shape
-        touch = np.zeros(b, bool)
-        if self.index._outlier_lo is not None:
-            touch = np.all(
-                (rects[:, :, 0] <= self.index._outlier_hi)
-                & (rects[:, :, 1] > self.index._outlier_lo), axis=1)
-        if self.o_img is not None and touch.any():
-            # nav == full rect for the full-dim outlier grid
-            of, ol, oncq = self.o_img.probe_batch(rects)
-            oncq = np.where(touch, oncq, 0)
-            if self._over_cell_cap(oncq):
-                return None
-            cells_probed += int(oncq.sum())
-            nbytes += self._add_grid_segs(self.o_img, self.outlier.row_ids,
-                                          rects, rects, of, ol, oncq, bp,
-                                          out, qmask=touch)
-        segs, cfgs, ids_list = out["segs"], out["cfgs"], out["ids"]
-
-        delta = self._delta
-        if delta is not None:
-            flo = np.full((bp, rects.shape[1]), np.inf, np.float32)
-            fhi = np.full((bp, rects.shape[1]), -np.inf, np.float32)
-            flo[:b] = f32_ceil(rects[:, :, 0])
-            fhi[:b] = f32_ceil(rects[:, :, 1])
-            segs.append({"rows": delta["rows_l"], "alive": delta["alive"],
-                         "flo": jnp.asarray(flo), "fhi": jnp.asarray(fhi)})
-            cfgs.append((self.hit_cap, False, False, self.use_pallas,
-                         self.interpret, 0))
-            ids_list.append(delta["ids"])
-            out["qmaps"].append(None)
-            out["bs"].append(b)
-            nbytes += flo.size * 8
+            delta = self._delta
+            if delta is not None:
+                flo = np.full((bp, rects.shape[1]), np.inf, np.float32)
+                fhi = np.full((bp, rects.shape[1]), -np.inf, np.float32)
+                flo[:b] = f32_ceil(rects[:, :, 0])
+                fhi[:b] = f32_ceil(rects[:, :, 1])
+                segs.append({"rows": delta["rows_l"],
+                             "alive": delta["alive"],
+                             "flo": jnp.asarray(flo),
+                             "fhi": jnp.asarray(fhi)})
+                cfgs.append((self.hit_cap, False, False, self.use_pallas,
+                             self.interpret, 0))
+                ids_list.append(delta["ids"])
+                out["qmaps"].append(None)
+                out["bs"].append(b)
+                nbytes += flo.size * 8
+            self._count_h2d(nbytes)
+            if sp is not None:
+                sp.args["bytes_h2d"] = up + nbytes
 
         res = self._dispatch(segs, cfgs) if segs else ()
-        self._count_h2d(nbytes)
         return {"b": b, "res": res, "ids": ids_list, "cells": cells_probed,
                 "qmaps": out["qmaps"], "bs": out["bs"],
                 "nav": nav_rects, "rects": rects, "touch": touch,
@@ -790,8 +811,9 @@ class CoaxDevicePlan(_PlanBase):
         n_over = int(over.sum())
         if n_over:
             qsel = np.nonzero(over)[0]
-            qo, ro, extra = self._reanswer(ticket, qsel)
-            _check_reanswer(total[qsel], qo)
+            with obs.span("device.reanswer", queries=n_over):
+                qo, ro, extra = self._reanswer(ticket, qsel)
+                _check_reanswer(total[qsel], qo)
             parts_q.append(qsel[qo])
             parts_r.append(ro)
             rows_scanned += extra

@@ -317,14 +317,17 @@ class BatchQueryExecutor:
         return split_hits(qids, rids, wave.shape[0])
 
     # -- split wave API (device pipelining; DESIGN.md §4) -------------- #
-    def execute_submit(self, rects: Sequence[np.ndarray]):
+    def execute_submit(self, rects: Sequence[np.ndarray],
+                       arrivals: Optional[np.ndarray] = None):
         """Submit ONE wave (≤ ``max_batch`` rects) without draining it.
 
         Returns an opaque pending handle for ``execute_collect``, or
         ``None`` when the index has no split wave API / the backend is not
         the device plane — callers then fall back to ``execute``.  The
         device plan snapshots epoch + delta + tombstones here, so writes
-        applied before the drain don't leak into the wave."""
+        applied before the drain don't leak into the wave.  ``arrivals``
+        (``perf_counter`` stamps, one per rect) give the traced ``wave``
+        span its ``queue_wait_s``: the span's start minus each arrival."""
         if not (self._batched and self.backend == "device"
                 and hasattr(self.index, "query_batch_submit")):
             return None
@@ -333,6 +336,8 @@ class BatchQueryExecutor:
         tr = obs.tracer()
         wsp = tr.start("wave", queries=int(wave.shape[0]),
                        backend="device") if tr else None
+        if wsp is not None and arrivals is not None:
+            wsp.args["queue_wait_s"] = (wsp.t0 - arrivals).tolist()
         t0 = time.perf_counter()
         if wsp is not None:
             with tr.attach(wsp):       # dispatch/cache spans nest under it

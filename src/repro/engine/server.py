@@ -265,7 +265,8 @@ class QueryServer:
 
         With tracing enabled (``obs.enable_tracing``) the whole call is
         one ``server.drain`` span parenting every ``wave`` span the
-        executor opens (DESIGN.md §10.2).
+        executor opens (DESIGN.md §10.2); each device ``wave`` span
+        carries its queries' ``queue_wait_s`` (wave start minus arrival).
         """
         with obs.span("server.drain", pending=len(self._pending)):
             return self._drain(max_waves)
@@ -293,7 +294,9 @@ class QueryServer:
             for q in wave:                     # claimed at formation so the
                 del self._pending[q.qid]       # next wave can't re-pick them
             waves_this_call += 1
-            pending = self.executor.execute_submit(rects)
+            arrivals = (np.array([q.arrival for q in wave])
+                        if obs.tracer() is not None else None)
+            pending = self.executor.execute_submit(rects, arrivals)
             if pending is not None:            # pipelined device path
                 inflight.append((wave, pending))
                 if len(inflight) >= 2:

@@ -167,6 +167,7 @@ def _make_kernel(d: int, kk: int, has_sort: bool, groups: int):
     return kernel
 
 
+@jax.named_scope("compact_hits")    # in the trace, apart from the kernel
 def compact_hits(words, hit_cap: int):
     """Hit bitmaps ``(Bp, N/4096, 128)`` i32 -> ``(counts (Bp, 1) i32,
     hits (Bp, hit_cap) i32)``: each query's true hit count and its first

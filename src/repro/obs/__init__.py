@@ -1,6 +1,6 @@
 """Unified telemetry plane (DESIGN.md §10).
 
-Three layers, zero dependencies:
+Two layers, zero import-time dependencies:
 
 1. **Metrics registry** (`metrics.py`) — process-global counters,
    gauges and log-bucketed histograms with labeled families; every
@@ -10,9 +10,10 @@ Three layers, zero dependencies:
 2. **Span tracing** (`trace.py`) — opt-in (``obs.enable_tracing()``);
    when no tracer is installed every ``obs.span(...)`` site is a
    cheap no-op, which is how the telemetry-off path stays at zero
-   overhead beyond the registry.
-3. **Profiling hooks** (`profile.py`) — ``obs.profile(logdir)`` gates
-   ``jax.profiler`` capture around device waves.
+   overhead beyond the registry.  An enabled tracer mirrors every span
+   into ``jax.profiler``'s trace (``TraceAnnotation``), so a capture
+   taken with ``jax.profiler.start_trace`` shows the program's spans on
+   the device's clock (DESIGN.md §10.4).
 
 `watchdog.py` builds the serving-pause monitor on layers 1+2.
 """
@@ -23,14 +24,13 @@ from typing import Optional, Union
 
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       get_registry, parse_text_exposition, set_registry)
-from .profile import profile
 from .trace import Span, Tracer
 from .watchdog import PauseWatchdog
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "PauseWatchdog",
     "Span", "Tracer", "disable_tracing", "enable_tracing", "get_registry",
-    "metrics", "parse_text_exposition", "profile", "set_registry",
+    "metrics", "parse_text_exposition", "set_registry",
     "set_tracer", "span", "stage_timer", "tracer",
 ]
 
@@ -53,8 +53,10 @@ def set_tracer(t: Optional[Tracer]) -> Optional[Tracer]:
     return prev
 
 
-def enable_tracing(capacity: int = 8192) -> Tracer:
-    """Install a fresh global ring-buffered tracer and return it."""
+def enable_tracing(capacity: int = 65536) -> Tracer:
+    """Install a fresh global ring-buffered tracer and return it.  Its
+    spans also open ``jax.profiler.TraceAnnotation``s of the same name,
+    which a running ``jax.profiler`` capture records."""
     t = Tracer(capacity=capacity)
     set_tracer(t)
     return t

@@ -13,6 +13,12 @@ override for the two places that legitimately cross that model:
 * background threads (compactor build, replication pump), which carry
   the spawning span across the thread boundary.
 
+Profiler mirror: a tracer also opens a ``jax.profiler.TraceAnnotation``
+of the same name at every ``start`` and closes it at ``finish``, so while a
+``jax.profiler`` capture runs each span lands in the device trace's
+``/host:`` plane under its own name, on the thread that finished it, with
+its scalar start-time arguments as stats.
+
 Export: ``events()`` (finished-span dicts), ``dump_jsonl``, and
 ``to_chrome()`` — Chrome ``trace_event`` JSON that ``chrome://tracing``
 / Perfetto opens as a wave timeline.  ``validate()`` is the CI gate:
@@ -36,7 +42,7 @@ class Span:
     as a context manager, or ``start``/``finish`` for intervals whose
     ends live in different call frames (submit vs collect)."""
 
-    __slots__ = ("name", "id", "parent", "t0", "t1", "args", "tid")
+    __slots__ = ("name", "id", "parent", "t0", "t1", "args", "tid", "ann")
 
     def __init__(self, name: str, id: int, parent: Optional[int],
                  t0: float, tid: int, args: Dict[str, object]):
@@ -47,6 +53,7 @@ class Span:
         self.t1: Optional[float] = None
         self.args = args
         self.tid = tid
+        self.ann = None             # the open profiler annotation, if any
 
     def to_dict(self) -> dict:
         return {"name": self.name, "id": self.id, "parent": self.parent,
@@ -55,10 +62,15 @@ class Span:
 
 
 class Tracer:
-    """Thread-safe bounded-ring span recorder on ``time.perf_counter``."""
+    """Thread-safe bounded-ring span recorder on ``time.perf_counter``,
+    mirrored into the profiler trace (module docstring).  jax is imported
+    here, not when ``repro.obs`` is."""
 
     def __init__(self, capacity: int = 8192):
+        from jax.profiler import TraceAnnotation
+
         self.capacity = int(capacity)
+        self._annotate = TraceAnnotation
         self._ring: deque = deque(maxlen=self.capacity)
         self._ids = itertools.count(1)
         self._open: Dict[int, Span] = {}
@@ -92,18 +104,25 @@ class Tracer:
             pid = parent.id if isinstance(parent, Span) else int(parent)
         sp = Span(name, next(self._ids), pid, time.perf_counter(),
                   threading.get_ident(), args)
+        sp.ann = self._annotate(name, **{
+            k: v for k, v in args.items()
+            if isinstance(v, (bool, int, float, str))})
         with self._lock:
             self._open[sp.id] = sp
         return sp
 
     def finish(self, span: Span, **args) -> Span:
         span.t1 = time.perf_counter()
+        if span.ann is not None:
+            span.ann.__exit__(None, None, None)
+            span.ann = None
         if args:
             span.args.update(args)
         # a span finished on a different thread than it started (the
         # §10.2 thread-boundary handoff) takes the finishing thread's
         # lane: that is where the work ran, and validate() uses the tid
-        # mismatch to exempt it from same-thread parent containment
+        # mismatch to exempt it from same-thread parent containment; its
+        # profiler annotation, closed above, records on that thread too
         span.tid = threading.get_ident()
         with self._lock:
             self._open.pop(span.id, None)

@@ -429,3 +429,33 @@ def test_server_pipelined_drain_device_equals_numpy():
     assert s["backend"] == "device" and s["waves_drained"] >= 3
     assert s["device_fallbacks"] == 0
     assert srv_d.executor.index.device_stats()["dispatches"] == s["waves"]
+
+
+@pytest.mark.parametrize("plan", ["coax", "grid"])
+def test_reanswer_span_on_hit_cap_overflow(plan):
+    """A wave with a query over ``hit_cap`` records one ``device.reanswer``
+    span, after the wave's transfer, naming how many queries it answered."""
+    from repro import obs
+
+    ds = make_airline(6_000, seed=4)
+    rects = rects_for(ds.data, n=10, seed=5)     # includes a full-range rect
+    if plan == "coax":
+        idx = COAXIndex(ds.data, backend="device",
+                        device_opts={"hit_cap": 16})
+        query = lambda: idx.query_batch(rects)
+    else:
+        idx = GridFile(ds.data, index_dims=[0, 1, 2], cells_per_dim=5,
+                       backend="device", device_opts={"hit_cap": 16})
+        query = lambda: idx.query_batch(rects[:, :3], rects)
+    tr = obs.enable_tracing()
+    try:
+        query()
+    finally:
+        obs.disable_tracing()
+    overflows = idx.last_batch_stats.hit_overflows
+    assert overflows > 0
+    evs = tr.events()
+    re = [e for e in evs if e["name"] == "device.reanswer"]
+    assert [e["args"]["queries"] for e in re] == [overflows]
+    transfer = [e for e in evs if e["name"] == "device.transfer"]
+    assert len(transfer) == 1 and transfer[0]["t1"] <= re[0]["t0"]

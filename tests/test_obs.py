@@ -337,3 +337,56 @@ def test_server_drain_span_and_watchdog_wiring():
     assert "server.drain" in names
     s = srv.stats()
     assert "pauses" in s and "pause_median_gap_s" in s
+
+
+def test_span_without_tracer_is_the_shared_null_context():
+    obs.disable_tracing()
+    assert obs.span("wave", queries=3) is obs._NULL_CTX
+    obs.enable_tracing()
+    assert obs.span("wave") is not obs._NULL_CTX
+    obs.disable_tracing()
+    assert obs.span("device.probe") is obs._NULL_CTX
+
+
+MIRRORED = ("server.drain", "wave", "device.probe", "device.inputs",
+            "device.dispatch", "device.transfer", "device.wait",
+            "device.copy")
+
+
+def test_spans_mirror_into_the_profiler_trace(tmp_path):
+    """Under a ``jax.profiler`` capture every program span of a device
+    drain also lands on the serving thread's line of the ``/host:`` plane,
+    under its own name and lasting as long as the ring says."""
+    import jax
+    from jax.profiler import ProfileData
+
+    ds = make_airline(3000)
+    rects = rects_for(ds.data)
+    srv = QueryServer(COAXIndex(ds.data), max_batch=4, backend="device")
+    srv.submit_many(rects)
+    srv.drain()                                   # compile outside the trace
+    tr = obs.enable_tracing()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        srv.submit_many(rects)
+        srv.drain()
+    finally:
+        jax.profiler.stop_trace()
+    ring = [e for e in tr.events() if e["name"] in MIRRORED]
+    assert {e["name"] for e in ring} == set(MIRRORED)
+    assert sum(e["name"] == "wave" for e in ring) > 2    # waves overlapped
+    (pb,) = tmp_path.rglob("*.xplane.pb")
+    lines = [list(ln.events) for p in ProfileData.from_file(str(pb)).planes
+             if p.name.startswith("/host:") for ln in p.lines]
+    (serving,) = [evs for evs in lines
+                  if any(ev.name == "server.drain" for ev in evs)]
+    for name in MIRRORED:
+        ann = sorted((ev.start_ns, ev.duration_ns * 1e-9) for ev in serving
+                     if ev.name == name)
+        rec = sorted((e["t0"], e["t1"] - e["t0"]) for e in ring
+                     if e["name"] == name)
+        assert len(ann) == len(rec), name
+        for (_, a), (_, r) in zip(ann, rec):
+            assert abs(a - r) <= max(0.05 * r, 50e-6), (name, a, r)
