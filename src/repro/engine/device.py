@@ -31,7 +31,16 @@ per-row plane in the kernel's lane layout ``(·, N_pad/128, 128)``
 Per wave, every segment — primary grid, outlier grid, and the fixed-shape
 delta/tombstone image of the live append log — goes into ONE jitted
 ``_wave_program`` dispatch (``dispatch_count`` asserts one launch per
-wave).  On the CPU-oracle route the grid segments additionally ship
+wave).  On the Pallas route each grid segment ships a work list
+(``_GridImage.work_list``) from the same probe pass: a query's cell box is
+a few contiguous row runs (rows are cell-major), and the kernel reads only
+the row tiles those runs overlap, ``W`` items a wave at most (``W`` = the
+tile count of the plan's largest grid image, a static shape).  A wave
+whose list exceeds ``W`` takes the full scan, in the same compiled
+program.  Segments with no probe stage (the delta image, single-cell
+grids) scan every row, their candidacy being liveness.  Every listed tile
+runs the full per-row test, so answers equal the full scan's bit for
+bit.  On the CPU-oracle route the grid segments additionally ship
 per-query candidate gather-index images (and skew-split into thin/fat
 sub-segments, still one dispatch) so per-wave work scales with candidate
 counts, not table size — DESIGN.md §4 "CPU oracle fast path".  Outputs
@@ -51,7 +60,8 @@ Overflow contracts (both exact):
   * ``cell_cap`` — the CPU-oracle route only, whose gather work grows with
     candidate cells: detected at SUBMIT from the host probe; the whole wave
     is answered by the numpy path (``fallbacks`` stat).  The Pallas route
-    scans every row whatever the probe says and answers every wave.
+    reads its listed tiles, or every tile when the list does not fit, and
+    answers every wave.
 
 Shape bucketing: wave width pads to a pow2 bucket (min ``min_bucket``),
 grid and delta images to a pow2 row count (min 4096, one kernel word
@@ -81,7 +91,7 @@ from ..core.types import sorted_contains
 from ..kernels import ref
 from ..kernels._platform import on_cpu, resolve_interpret
 from ..kernels.fused_scan import (GROUP_ROWS, fused_scan_call, pad_to_lanes,
-                                  to_lanes)
+                                  tile_rows, to_lanes)
 
 __all__ = ["DevicePlan", "CoaxDevicePlan", "f32_floor"]
 
@@ -120,16 +130,19 @@ def _wave_program(segs, config):
 
     ``segs`` is a tuple of array dicts (a pytree), ``config`` the matching
     tuple of static per-segment tuples ``(hit_cap, probe, has_sort,
-    use_pallas, interpret, gw)``.  Each segment runs the fused megakernel
-    (the Pallas kernel on accelerators, its jnp oracle — same contract — on
-    CPU; ``gw > 0`` additionally restricts the oracle to each query's
-    probe-derived candidate rows via a gather-index image, an
-    exactness-preserving CPU fast path) and returns its compacted
-    ``(counts, hits, scanned)``.
+    use_pallas, interpret, gw, nw)``.  Each segment runs the fused
+    megakernel (the Pallas kernel on accelerators, its jnp oracle — same
+    contract — on CPU) and returns its compacted ``(counts, hits,
+    scanned)``.  ``nw > 0`` gives the kernel the segment's ``(2·nw,)`` work
+    list of ``(tile, query)`` items, so each query reads only the tiles
+    holding its candidate rows (or every tile, when the host marks the list
+    as overflowing); ``gw > 0`` restricts the oracle to each query's
+    probe-derived candidate rows via a gather-index image.  Both are
+    exactness-preserving.
     """
     out = []
     for seg, (hit_cap, probe, has_sort, use_pallas, interpret,
-              gw) in zip(segs, config):
+              gw, nw) in zip(segs, config):
         kwargs = {}
         if probe:
             kwargs.update(coords=seg["coords"], first=seg["first"],
@@ -137,6 +150,8 @@ def _wave_program(segs, config):
         if has_sort:
             kwargs.update(sv=seg["sv"], tband=seg["tband"])
         if use_pallas:
+            if nw:
+                kwargs["work"] = seg["work"]
             out.append(fused_scan_call(
                 seg["rows"], seg["flo"], seg["fhi"], seg["alive"],
                 hit_cap=hit_cap, interpret=interpret, **kwargs))
@@ -184,6 +199,8 @@ class _GridImage:
         # layout (``fused_scan.to_lanes``).
         n_pad = max(GROUP_ROWS, _next_pow2(n + 1))
         self.n_pad = n_pad
+        self.rows_per_tile = tile_rows(n_pad)      # one kernel grid step
+        self.tiles = n_pad // self.rows_per_tile
         rows = pad_to_lanes(grid.rows.T, n_pad, np.inf, np.float32)
         self.rows = jnp.asarray(rows)
         self.bytes_resident = rows.nbytes
@@ -264,6 +281,56 @@ class _GridImage:
             lists.append(_multi_arange(starts, lens))
         return lists
 
+    def work_list(self, first, last, live: np.ndarray, nw: int) -> np.ndarray:
+        """The wave's ``(tile, query)`` work list for the listed kernel, from
+        the SAME probe pass, as one flat ``(2·nw,)`` int32 vector: tiles,
+        then queries.
+
+        Rows are cell-major, so a query's cell box is one contiguous row
+        run per combination of its leading grid dims' cells, each covering
+        the last grid dim's span: ``[offsets[c0], offsets[c1 + 1])``.  A
+        box of more runs than ``nw`` stands for its whole span
+        ``[offsets[first cell], offsets[last cell + 1])``.  Each run turns
+        into the tiles it overlaps; items go query by query, tiles ascending
+        and unique, and only ``live`` queries have items.  Items past the
+        last repeat its tile with query ``-1``.  A list of more than ``nw``
+        items is not built: every entry is ``-1``, and the kernel scans
+        every tile for every query."""
+        work = np.full(2 * nw, -1, np.int32)
+        q = np.nonzero(live)[0]
+        f, l = first[q].astype(np.int64), last[q].astype(np.int64)
+        lead = l[:, :-1] - f[:, :-1] + 1
+        runs = lead.prod(axis=1)
+        wide = runs > nw
+        runs[wide] = 1
+        rq = np.repeat(np.arange(q.size), runs)      # run -> live query
+        r = np.arange(rq.size) - np.repeat(np.cumsum(runs) - runs, runs)
+        c0 = np.zeros(rq.size, np.int64)
+        for j in range(self.k - 2, -1, -1):          # last lead dim fastest
+            c0 += (f[rq, j] + r % lead[rq, j]) * self._radix[j]
+            r //= lead[rq, j]
+        c1 = c0 + l[rq, -1]
+        c0 += f[rq, -1]
+        span = wide[rq]
+        c1[span] = l[rq[span]] @ self._radix
+        a, e = self.offsets_h[c0], self.offsets_h[c1 + 1]
+        keep = e > a
+        rq, a, e = rq[keep], a[keep], e[keep]
+        t0, t1 = a // self.rows_per_tile, (e - 1) // self.rows_per_tile
+        # a query's runs ascend and are disjoint: the next run starts at
+        # or past the tile where the last one ended
+        dup = np.zeros(rq.size, np.int64)
+        dup[1:] = (rq[1:] == rq[:-1]) & (t0[1:] == t1[:-1])
+        lens = t1 - t0 + 1 - dup
+        n = int(lens.sum())
+        if n > nw:
+            return work
+        tiles = _multi_arange(t0 + dup, lens)
+        work[:n] = tiles
+        work[n:nw] = tiles[-1] if n else 0
+        work[nw:nw + n] = np.repeat(q[rq], lens)
+        return work
+
     def gather_bucket(self, lists) -> int:
         """Static gather width for this wave: the max per-query candidate
         row count, pow2-bucketed (min 512) so steady-state waves share
@@ -275,7 +342,7 @@ class _GridImage:
 
     def seg_inputs(self, nav_rects, filter_rects, first, last, bp: int,
                    qmask: Optional[np.ndarray] = None,
-                   glists=None, gw: int = 0):
+                   glists=None, gw: int = 0, nw: int = 0):
         """Build this wave's padded per-query device inputs for one segment.
 
         Padding queries (and ``qmask``-suppressed ones, e.g. the §8.2.3
@@ -283,8 +350,12 @@ class _GridImage:
         rect, so they contribute no hits.  When ``gw > 0`` the per-query
         candidate lists ``glists`` ship as a ``(bp, gw)`` gather-index
         image for the oracle's candidate-gather scan (pad slots point at
-        the dead ``+inf`` pad row).  Returns ``(seg dict, uploaded
-        bytes)``; the static config tuple comes from ``config_for``.
+        the dead ``+inf`` pad row).  When ``nw > 0`` a probe segment ships
+        its ``work_list``.  Returns ``(seg dict, uploaded bytes, tiles)``,
+        ``tiles`` being ``(tiles the kernel reads, real queries x image
+        tiles, whether the list did not fit)`` for a listed segment and
+        ``None`` otherwise; the static config tuple comes from
+        ``config_for``.
         """
         b = nav_rects.shape[0]
         flo = np.full((bp, filter_rects.shape[1]), np.inf, np.float32)
@@ -297,6 +368,7 @@ class _GridImage:
         seg = {"rows": self.rows, "alive": self.alive,
                "flo": jnp.asarray(flo), "fhi": jnp.asarray(fhi)}
         nbytes = flo.size * 8
+        tiles = None
         if self.probe:
             k = first.shape[1]
             fa = np.ones((bp, k), np.int32)     # pad: empty range [1, 0]
@@ -314,6 +386,17 @@ class _GridImage:
                     gi[q, :lst.size] = lst[:gw]
                 seg["gidx"] = jnp.asarray(gi)
                 nbytes += gi.size * 4
+            if nw:
+                live = (last >= first).all(axis=1)
+                if qmask is not None:
+                    live &= qmask
+                work = self.work_list(first, last, live, nw)
+                seg["work"] = jnp.asarray(work)
+                nbytes += work.nbytes
+                full = bool(work[0] < 0)
+                image = b * self.tiles
+                tiles = (image if full else int((work[nw:] >= 0).sum()),
+                         image, full)
         if self.has_sort:
             tb = np.full((bp, 2), np.inf, np.float32)
             tb[:, 1] = -np.inf                   # pad: empty band [inf, -inf)
@@ -323,14 +406,15 @@ class _GridImage:
             seg["sv"] = self.sv
             seg["tband"] = jnp.asarray(tb)
             nbytes += tb.size * 4
-        return seg, nbytes
+        return seg, nbytes, tiles
 
     def config_for(self, hit_cap: int, use_pallas: bool, interpret: bool,
-                   gw: int = 0) -> tuple:
-        # the Pallas kernel path always scans full-N (the accelerator
-        # design); the gather is the CPU oracle's candidate-scaling lever
-        return (hit_cap, self.probe, self.has_sort,
-                use_pallas, interpret, 0 if use_pallas else int(gw))
+                   gw: int = 0, nw: int = 0) -> tuple:
+        # the Pallas kernel follows the work list of a probe segment; the
+        # gather is the CPU oracle's candidate-scaling lever
+        return (hit_cap, self.probe, self.has_sort, use_pallas, interpret,
+                0 if use_pallas else int(gw),
+                int(nw) if use_pallas and self.probe else 0)
 
 
 def _check_reanswer(dev_counts: np.ndarray, host_q: np.ndarray) -> None:
@@ -392,10 +476,42 @@ class _PlanBase:
     def bucket(self, b: int) -> int:
         return max(self.min_bucket, _next_pow2(b))
 
+    def _list_width(self, images) -> int:
+        """``W``, the static length of every work list this plan ships: the
+        tile count of its largest probe image on the Pallas route (so a
+        wave's padding steps cost at most one pass over that image), 0 on
+        the CPU-oracle route."""
+        if not self.use_pallas:
+            return 0
+        return max((img.tiles for img in images
+                    if img is not None and img.probe), default=0)
+
+    def _count_tiles(self, tiles, sp) -> None:
+        """Fold one wave's ``(listed, image, full)`` tile counts of its listed
+        segments (``None`` for the others) into the ``device.inputs`` span
+        and the global registry (``coax_device_tiles_read_total``; a segment
+        whose list did not fit reads its whole image and counts in
+        ``coax_device_fullscan_segments_total``)."""
+        tiles = [t for t in tiles if t]
+        if not tiles:
+            return
+        listed = sum(t[0] for t in tiles)
+        if sp is not None:
+            sp.args["tiles_listed"] = listed
+            sp.args["tiles_image"] = sum(t[1] for t in tiles)
+        g = obs.get_registry()
+        g.counter("coax_device_tiles_read_total",
+                  "row tiles the wave kernel read").inc(listed)
+        full = sum(t[2] for t in tiles)
+        if full:
+            g.counter("coax_device_fullscan_segments_total",
+                      "probe segments whose work list did not fit: every "
+                      "tile for every query").inc(full)
+
     def _over_cell_cap(self, n_cells_q: np.ndarray) -> bool:
         """The CPU-oracle route's submit-time budget (its gather work grows
-        with candidate cells).  The Pallas route scans every row whatever
-        the probe says, so it never declines a wave."""
+        with candidate cells).  The Pallas route reads the tiles the probe
+        lists, or every tile, so it never declines a wave."""
         return (not self.use_pallas
                 and int(n_cells_q.max(initial=0)) > self.cell_cap)
 
@@ -476,8 +592,9 @@ class DevicePlan(_PlanBase):
         whose gather work grows with candidate cells: waves where any
         query's directory probe exceeds it return ``None`` from
         ``submit_wave`` so the caller answers them on the numpy path.  The
-        Pallas route scans every row whatever the probe says, so it has no
-        such budget and answers every wave (§4).
+        Pallas route reads the tiles of each query's work list, or every
+        tile when the wave's list does not fit, so it has no such budget
+        and answers every wave (§4).
     hit_cap : per-query device hit-buffer budget; queries whose exact count
         exceeds it are re-answered on the host at drain time (§4).
     min_bucket : smallest wave bucket; B pads up to ``max(min_bucket,
@@ -499,6 +616,7 @@ class DevicePlan(_PlanBase):
         self._img = _GridImage(grid) if grid.n_rows else None
         if self._img is not None:
             self._count_h2d(self._img.bytes_resident)
+        self._nw = self._list_width([self._img])
 
     # ------------------------------------------------------------------ #
     def submit_wave(self, nav_rects: np.ndarray, filter_rects: np.ndarray):
@@ -518,14 +636,15 @@ class DevicePlan(_PlanBase):
             if not self.use_pallas:
                 glists = self._img.candidate_lists(first, last, n_cells_q)
                 gw = self._img.gather_bucket(glists)
-            seg, nbytes = self._img.seg_inputs(nav_rects, filter_rects,
-                                               first, last, bp,
-                                               glists=glists, gw=gw)
+            seg, nbytes, tiles = self._img.seg_inputs(
+                nav_rects, filter_rects, first, last, bp,
+                glists=glists, gw=gw, nw=self._nw)
             self._count_h2d(nbytes)
+            self._count_tiles([tiles], sp)
             if sp is not None:
                 sp.args["bytes_h2d"] = nbytes
         cfg = self._img.config_for(self.hit_cap, self.use_pallas,
-                                   self.interpret, gw)
+                                   self.interpret, gw, self._nw)
         res = self._dispatch([seg], [cfg])
         return {"b": b, "res": res, "cells": int(n_cells_q.sum()),
                 "nav": nav_rects, "filt": filter_rects}
@@ -599,6 +718,7 @@ class CoaxDevicePlan(_PlanBase):
         for img in (self.p_img, self.o_img):
             if img is not None:
                 self._count_h2d(img.bytes_resident)
+        self._nw = self._list_width([self.p_img, self.o_img])
         self._dead_key = None
         self._dead_host = np.empty(0, np.int64)
         self._delta_key = None
@@ -679,12 +799,13 @@ class CoaxDevicePlan(_PlanBase):
             thin_mask[fat] = False
             thin_lists = [l if m else np.empty(0, np.int64)
                           for l, m in zip(glists, thin_mask)]
-        seg, nb = img.seg_inputs(nav, filt, first, last, bp,
-                                 qmask=thin_mask, glists=thin_lists,
-                                 gw=gw_thin)
+        seg, nb, tiles = img.seg_inputs(nav, filt, first, last, bp,
+                                        qmask=thin_mask, glists=thin_lists,
+                                        gw=gw_thin, nw=self._nw)
         out["segs"].append(seg)
         out["cfgs"].append(img.config_for(self.hit_cap, self.use_pallas,
-                                          self.interpret, gw_thin))
+                                          self.interpret, gw_thin, self._nw))
+        out["tiles"].append(tiles)
         out["ids"].append(ids)
         out["qmaps"].append(None)
         out["bs"].append(b)
@@ -693,9 +814,9 @@ class CoaxDevicePlan(_PlanBase):
             bp_f = max(self.min_bucket, _next_pow2(fat.size))
             flists = [glists[q] for q in fat]
             gw_f = img.gather_bucket(flists)
-            seg, nb = img.seg_inputs(nav[fat], filt[fat], first[fat],
-                                     last[fat], bp_f,
-                                     glists=flists, gw=gw_f)
+            seg, nb, _ = img.seg_inputs(nav[fat], filt[fat], first[fat],
+                                        last[fat], bp_f,
+                                        glists=flists, gw=gw_f)
             out["segs"].append(seg)
             out["cfgs"].append(img.config_for(
                 self.hit_cap, self.use_pallas, self.interpret, gw_f))
@@ -738,7 +859,8 @@ class CoaxDevicePlan(_PlanBase):
                 cells_probed += int(p[2].sum())
 
         bp = self.bucket(b)
-        out = {"segs": [], "cfgs": [], "ids": [], "qmaps": [], "bs": []}
+        out = {"segs": [], "cfgs": [], "ids": [], "qmaps": [], "bs": [],
+               "tiles": []}
         with obs.span("device.inputs") as sp:
             nbytes = 0
             up = self._refresh_writes()
@@ -763,12 +885,13 @@ class CoaxDevicePlan(_PlanBase):
                              "flo": jnp.asarray(flo),
                              "fhi": jnp.asarray(fhi)})
                 cfgs.append((self.hit_cap, False, False, self.use_pallas,
-                             self.interpret, 0))
+                             self.interpret, 0, 0))
                 ids_list.append(delta["ids"])
                 out["qmaps"].append(None)
                 out["bs"].append(b)
                 nbytes += flo.size * 8
             self._count_h2d(nbytes)
+            self._count_tiles(out["tiles"], sp)
             if sp is not None:
                 sp.args["bytes_h2d"] = up + nbytes
 

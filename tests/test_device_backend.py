@@ -459,3 +459,148 @@ def test_reanswer_span_on_hit_cap_overflow(plan):
     assert [e["args"]["queries"] for e in re] == [overflows]
     transfer = [e for e in evs if e["name"] == "device.transfer"]
     assert len(transfer) == 1 and transfer[0]["t1"] <= re[0]["t0"]
+
+
+# --------------------------------------------------------------------- #
+# Work-list kernel (DESIGN.md §4): each query reads only its tiles
+# --------------------------------------------------------------------- #
+TILED_ROWS = 200_000          # padded to 2^18 rows: 8 kernel tiles
+
+
+@pytest.fixture(scope="module")
+def tiled_grid():
+    """8 kernel tiles of a 16 x 16-cell grid on dims 0 and 1, sorted on
+    dim 2: a box is one row run per dim-0 cell, runs ~12,500 rows apart."""
+    rng = np.random.default_rng(51)
+    data = rng.normal(0, 10, (TILED_ROWS, 3)).astype(np.float32)
+    return GridFile(data, index_dims=[0, 1, 2], cells_per_dim=16, sort_dim=2)
+
+
+def _near(gf, rng, b, half):
+    """``b`` rects of half-widths ``half`` around rows of the grid."""
+    c = gf.rows[rng.choice(gf.n_rows, b, replace=False)].astype(np.float64)
+    return np.stack([c - half, c + half], axis=-1)
+
+
+def _listed_wave(gf, case):
+    """(rects, bucket, hit_cap, dead row ids) of one case."""
+    rng = np.random.default_rng(1)
+    several = _near(gf, rng, 4, np.array([2.0, 3.0, 5.0]))  # 7 of 8 items
+    if case == "runs_and_tiles":
+        return several, 4, 64, None
+    if case == "empty_and_padding":
+        empty = several[:1, :, ::-1].copy()         # lo > hi: an empty box
+        point = gf.rows[:1, :, None].astype(np.float64) + [0.0, 0.0]
+        return np.concatenate([empty, point, several[:1]]), 8, 64, None
+    if case == "tombstones":
+        dead = np.sort(rng.choice(gf.row_ids, TILED_ROWS // 3, replace=False))
+        return several, 4, 64, dead
+    if case == "past_hit_cap":
+        return several, 4, 8, None
+    assert case == "list_overflow"       # every query every tile: 32 > W
+    return np.repeat(full_rect(3)[None], 4, axis=0), 4, 64, None
+
+
+@pytest.mark.parametrize("case", ["runs_and_tiles", "empty_and_padding",
+                                  "tombstones", "past_hit_cap",
+                                  "list_overflow"])
+def test_listed_kernel_matches_full_scan(tiled_grid, case):
+    """The work-list kernel (interpret mode) against the full scan and a
+    brute-force scan of the grid: counts, hit prefixes and ``scanned``
+    equal, with the list at the plan's width ``W`` (the image's 8 tiles);
+    a list past ``W`` takes the full-scan branch of the same program."""
+    from repro.core.gridfile import f32_ceil
+    from repro.engine.device import _GridImage
+    from repro.kernels.fused_scan import fused_scan
+
+    gf = tiled_grid
+    rects, bp, cap, dead = _listed_wave(gf, case)
+    img = _GridImage(gf)
+    assert img.tiles == 8
+    if dead is not None:
+        img.set_alive(dead)
+    first, last, n_cells = img.probe_batch(rects)
+    seg, _, tiles = img.seg_inputs(rects, rects, first, last, bp,
+                                   nw=img.tiles)
+    b = rects.shape[0]
+    assert tiles[1] == b * img.tiles
+    assert tiles[2] == (case == "list_overflow")
+    work = np.asarray(seg["work"])
+    if case == "empty_and_padding":                  # no items for either
+        assert n_cells[0] == 0 and 0 not in work[8:]
+        assert 1 in work[8:] and 2 in work[8:]
+    if case == "runs_and_tiles":
+        assert (last[:, 0] - first[:, 0]).max() >= 1     # several runs
+        per_query = np.bincount(work[8:][work[8:] >= 0], minlength=b)
+        assert per_query.max() >= 2 and tiles[0] < tiles[1]
+
+    ops = [seg[k] for k in ("rows", "flo", "fhi", "alive", "coords", "first",
+                            "last", "sv", "tband")]
+    listed = fused_scan(*ops, work=seg["work"], hit_cap=cap, interpret=True)
+    full = fused_scan(*ops, hit_cap=cap, interpret=True)
+    for x, y in zip(listed, full):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+    # brute force over the grid's own cell-major rows
+    alive = np.ones(gf.n_rows, bool)
+    if dead is not None:
+        alive = ~np.isin(gf.row_ids, dead)
+    rows = gf.rows.astype(np.float64)
+    cell = np.repeat(np.arange(gf.n_cells), np.diff(gf.offsets))
+    coords = np.stack([cell // 16, cell % 16], axis=1)
+    sv = gf.sort_vals
+    counts, hits, scanned = (np.asarray(x) for x in listed)
+    for q in range(bp):
+        if q >= b:                                      # padding: inert
+            assert counts[q, 0] == scanned[q, 0] == 0
+            continue
+        hit = alive & np.all((rows >= rects[q, :, 0])
+                             & (rows < rects[q, :, 1]), axis=1)
+        cand = (alive & np.all((coords >= first[q]) & (coords <= last[q]),
+                               axis=1)
+                & (sv >= f32_ceil(rects[q, 2, 0]))
+                & (sv < f32_ceil(rects[q, 2, 1])))
+        want = np.nonzero(hit)[0]
+        assert counts[q, 0] == want.size
+        assert scanned[q, 0] == cand.sum()
+        take = min(want.size, cap)
+        assert np.array_equal(hits[q, :take], want[:take])
+        assert (hits[q, take:] == -1).all()
+    if case == "past_hit_cap":
+        assert counts[:b, 0].max() > cap
+
+
+def test_listed_waves_share_one_shape_per_bucket():
+    """COAX waves whose lists read different numbers of tiles, and a wave
+    whose list does not fit (the full-scan branch), all run one compiled
+    program per bucket, answer as numpy does, and count their tiles on
+    the ``device.inputs`` span and in the registry."""
+    from repro import obs
+    from repro.data import knn_rect_queries
+
+    ds = make_airline(400_000, seed=3)
+    idx = COAXIndex(ds.data, device_opts={"use_pallas": True,
+                                          "interpret": True})
+    waves = [knn_rect_queries(ds.data, 4, k, seed=k) for k in (10, 100)]
+    waves.append(np.repeat(full_rect(ds.data.shape[1])[None], 3, axis=0))
+    full = obs.get_registry().counter("coax_device_fullscan_segments_total")
+    full0 = full.total()
+    tr = obs.enable_tracing()
+    try:
+        for rects in waves:
+            idx.backend = "numpy"
+            q_n, r_n = idx.query_batch(rects)
+            idx.backend = "device"
+            q_d, r_d = idx.query_batch(rects)
+            assert np.array_equal(q_d, q_n) and np.array_equal(r_d, r_n)
+    finally:
+        obs.disable_tracing()
+    plan = idx.device_plan()
+    assert plan._nw == plan.p_img.tiles == 16
+    assert plan.compile_count == 1
+    args = [e["args"] for e in tr.events() if e["name"] == "device.inputs"]
+    listed = [a["tiles_listed"] for a in args]
+    image = [a["tiles_image"] for a in args]
+    assert len(set(listed[:2])) == 2 and listed[0] < image[0]
+    assert listed[2] == image[2]              # the full-scan branch
+    assert full.total() - full0 >= 1

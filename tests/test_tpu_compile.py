@@ -3,10 +3,21 @@
 The TPU compiler ships with jaxlib, so a topology that is described but not
 attached takes the Pallas kernel through Mosaic exactly as the chip would:
 block-shape rules, unimplemented primitives and VMEM limits all surface
-here.  The shapes are the chip smoke's own (``chip_smoke.py`` at its
-default 20M airline rows): a primary segment of D=8 columns padded to 2^25
-rows with k=3 grid dims and a sorted dim, the full-dimensional outlier grid,
-and a delta segment, at the wave buckets B=4 and B=64.
+here.  Three sets of shapes, each at the wave buckets B=4 and B=64:
+
+* the chip smoke's own (``chip_smoke.py`` at its default 20M airline
+  rows): a primary segment of D=8 columns padded to 2^25 rows with k=3
+  grid dims and a sorted dim, the full-dimensional outlier grid, and a
+  delta segment;
+* the benchmark's airline table (80M rows x 8): primary 2^27 rows x k=3,
+  outlier 2^23 x k=7;
+* the benchmark's OSM table (105M rows x 4): primary 2^27 x k=2, outlier
+  2^25 x k=3.
+
+Every grid segment carries the plan's work list: ``W`` items, ``W`` being
+the tile count of the largest image (4,096 at 2^27 rows), so the
+scalar-prefetched list must fit SMEM beside the per-query scalars, and
+both the listed and the full-scan kernel lower in one program.
 
 The topology is described inside a module-scoped fixture — never at import
 — so every xdist worker collects the same tests and only the worker that
@@ -20,12 +31,15 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.engine.device import _wave_program
-from repro.kernels.fused_scan import DEFAULT_HIT_CAP, GROUP_ROWS
+from repro.kernels.fused_scan import DEFAULT_HIT_CAP, GROUP_ROWS, STEP_ROWS
 
-D = 8
-PRIMARY = (1 << 25, 3)        # (padded rows, grid dims): 18.4M primary rows
-OUTLIER = (1 << 21, 7)        # 1.6M outlier rows, every dim but the sorted
 DELTA_ROWS = GROUP_ROWS       # smallest delta image (holds up to 4096 rows)
+# (columns D, (padded rows, grid dims) of the primary, of the outlier grid)
+TABLES = {
+    "smoke": (8, (1 << 25, 3), (1 << 21, 7)),    # 18.4M / 1.6M rows
+    "airline-80m": (8, (1 << 27, 3), (1 << 23, 7)),
+    "osm-105m": (4, (1 << 27, 2), (1 << 25, 3)),
+}
 
 
 @pytest.fixture(scope="module")
@@ -56,33 +70,46 @@ def no_persistent_cache():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _segment(sharding, bp, n_pad, k=0, sort=False):
+def _segment(sharding, bp, d, n_pad, k=0, sort=False, nw=0):
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
     lanes = (n_pad // 128, 128)
-    seg = {"rows": s((D,) + lanes, jnp.float32),
+    seg = {"rows": s((d,) + lanes, jnp.float32),
            "alive": s(lanes, jnp.int32),
-           "flo": s((bp, D), jnp.float32), "fhi": s((bp, D), jnp.float32)}
+           "flo": s((bp, d), jnp.float32), "fhi": s((bp, d), jnp.float32)}
     if k:
         seg.update(coords=s((k,) + lanes, jnp.int32),
                    first=s((bp, k), jnp.int32), last=s((bp, k), jnp.int32))
+        seg["work"] = s((2 * nw,), jnp.int32)
     if sort:
         seg.update(sv=s(lanes, jnp.float32), tband=s((bp, 2), jnp.float32))
-    cfg = (DEFAULT_HIT_CAP, bool(k), sort, True, False, 0)
+    cfg = (DEFAULT_HIT_CAP, bool(k), sort, True, False, 0, nw if k else 0)
     return seg, cfg
 
 
 @pytest.mark.parametrize("bp", [4, 64])
-def test_wave_program_compiles_for_v5e(one_chip, no_persistent_cache, bp):
-    segs = [_segment(one_chip, bp, *PRIMARY, sort=True),
-            _segment(one_chip, bp, *OUTLIER, sort=True),
-            _segment(one_chip, bp, DELTA_ROWS)]
+@pytest.mark.parametrize("table", sorted(TABLES))
+def test_wave_program_compiles_for_v5e(one_chip, no_persistent_cache, bp,
+                                       table):
+    d, primary, outlier = TABLES[table]
+    nw = primary[0] // STEP_ROWS                  # the plan's list width
+    segs = [_segment(one_chip, bp, d, *primary, sort=True, nw=nw),
+            _segment(one_chip, bp, d, *outlier, sort=True, nw=nw),
+            _segment(one_chip, bp, d, DELTA_ROWS)]
     fn = jax.jit(_wave_program, static_argnums=1)
     compiled = fn.lower(tuple(s for s, _ in segs),
                         tuple(c for _, c in segs)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3     # one kernel per segment
+    # a listed and a full-scan kernel per grid segment, one for the delta
+    assert text.count("tpu_custom_call") >= 5
+    # only the kernel launches (and the tuple reads of their outputs) carry
+    # the kernel's name, so the trace's kernel time counts no enclosing
+    # operation, such as the lax.cond around them
+    named = [ln for ln in text.splitlines() if "coax_fused_scan" in ln
+             and " = " in ln]
+    assert named and all("custom-call(" in ln or "get-tuple-element(" in ln
+                         for ln in named), named
     out = jax.eval_shape(fn, tuple(s for s, _ in segs),
                          tuple(c for _, c in segs))
     for counts, hits, scanned in out:
