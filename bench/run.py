@@ -14,8 +14,11 @@ nothing compiles inside the window.
 
 Window: the traffic's arrival module drives ``QueryServer`` for
 ``--seconds``.  With ``--trace 1`` the window is traced (``jax.profiler``
-plus the program's ``repro.obs`` spans) and the per-layer metrics are
-reported instead of the end-to-end ones.
+plus the program's ``repro.obs`` spans) for at most ``TRACE_SECONDS``, and
+the per-layer metrics are reported instead of the end-to-end ones.
+Stopping the profiler takes about 1.6 s for each traced second on a TPU
+v5e (92 s after a 51 s airline window, 59 s after 30 s), so a traced
+window as long as the untraced one would not end within six minutes.
 
 Check: once the window has closed and the peak memory has been read, the
 program is freed and a sample of the answers served in the window (drawn
@@ -49,6 +52,7 @@ ROOT = Path(__file__).resolve().parents[1]
 SAMPLE = 512        # answers compared per run, drawn from the seed ...
 LARGEST = 32        # ... plus the largest answers served
 KERNEL = "coax_fused_scan"
+TRACE_SECONDS = 30.0    # a traced window's longest (see above)
 
 
 def _log(**kv) -> None:
@@ -159,6 +163,8 @@ def serve_window(cell, st, seed: int, seconds: float, traced: bool, log):
     from bench import seeding
     from repro import obs
 
+    if traced:
+        seconds = min(seconds, TRACE_SECONDS)
     arrivals = cell.traffic["arrivals"]
     drive = cell.module("traffic", arrivals["kind"])
     order = seeding.np_rng(seed, seeding.ORDER).permutation(st.rects.shape[0])
@@ -258,7 +264,10 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, peaks, devices,
         hits = [a.size for a in win["answers"].values()]
         import numpy as np
 
-        cand = work.candidate_rows(st.index, st.rects[rect_ids])
+        # a query's candidate rows depend on its rect alone: count each
+        # pool rect once, however often the window cycled through it
+        uniq, inv = np.unique(rect_ids, return_inverse=True)
+        cand = work.candidate_rows(st.index, st.rects[uniq])[inv]
         ctx = SimpleNamespace(
             trace=win["trace"], spans=win["spans"], served=win["served"],
             peaks=peaks, needed_bytes=work.needed_bytes(

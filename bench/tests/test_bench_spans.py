@@ -24,3 +24,18 @@ def test_traced_run_reports_the_span_metrics(workload):
     m = out["metrics"]
     assert names <= set(m)
     assert all(m[k]["value"] > 0 and m[k]["unit"] == "ms" for k in names)
+
+
+@pytest.mark.parametrize("traced, window", [(True, 0.2), (False, 0.4)],
+                         ids=["traced", "untraced"])
+def test_only_a_traced_window_is_cut_to_trace_seconds(monkeypatch, traced,
+                                                      window):
+    monkeypatch.setattr(run, "TRACE_SECONDS", 0.2)
+    logs = []
+    out = run.run_cell(tiny_cell("airline-80m.knn10-closed"), SEED, 0.4,
+                       traced, PEAKS, jax.devices(),
+                       log=lambda **kv: logs.append(kv), kernel_check=False)
+    assert out["correct"]
+    win = next(kv for kv in logs if kv["stage"] == "window")
+    assert win["seconds"] == window and win["elapsed_s"] >= window
+    assert (win["elapsed_s"] < 0.4) == traced

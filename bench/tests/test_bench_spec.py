@@ -72,3 +72,9 @@ def test_reader_that_finds_nothing_leaves_its_metric_out():
     ctx = NS(trace=None, spans=[], needed_bytes=None, peaks={},
              served={"queries": 0, "waves": 0, "hit_overflows": 0})
     assert spec.read_per_layer(cell, ctx) == {}
+
+
+def test_every_end_to_end_bound_is_a_share_up_to_a_quarter():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for m in bench["end_to_end"]:
+        assert 0 < m["bound"] <= 0.25, m["name"]
