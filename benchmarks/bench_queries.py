@@ -218,7 +218,8 @@ def run_batch(rows: int = 100_000, n_queries: int = 256,
                  f"rows_scanned={s['rows_scanned']},"
                  f"cells_probed={s['cells_probed']},"
                  f"fallbacks={s['device_fallbacks']},"
-                 f"hit_overflows={s['hit_overflows']}")
+                 f"hit_overflows={s['hit_overflows']},"
+                 f"large_results={s['large_results']}")
         if bk == "device":
             dstats = idx.device_stats()
             result["device_stats"] = dstats      # compile_count + transfers

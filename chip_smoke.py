@@ -145,6 +145,7 @@ def run_phases(rows: int, seed: int = 7, n_queries: int = 256,
             "waves": len(waves), "device_waves": dev_waves,
             "dispatches": dispatches, "fallback_waves": fallbacks,
             "hit_overflows": s1["hit_overflows"] - s0["hit_overflows"],
+            "large_results": s1["large_results"] - s0["large_results"],
             "hits_median": float(np.median(counts)),
             "hits_max": int(counts.max()),
             "compiled_shapes": ds1["compile_count"] - c0,
@@ -233,6 +234,8 @@ def main(argv=None) -> int:
          dispatches=summary["dispatches"],
          bytes_h2d=summary["bytes_h2d"], bytes_d2h=summary["bytes_d2h"],
          hit_overflows={k: v["hit_overflows"]
+                        for k, v in summary["phases"].items()},
+         large_results={k: v["large_results"]
                         for k, v in summary["phases"].items()})
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

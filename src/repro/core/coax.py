@@ -1262,13 +1262,15 @@ class COAXIndex:
         return q, r
 
     def device_stats(self) -> Optional[dict]:
-        """Device-plane rollups (compile cache size, kernel dispatches,
-        transfer bytes both ways), or None before any device wave."""
+        """Device-plane rollups (compile cache size, wave dispatches, the
+        large pass's dispatches, transfer bytes both ways), or None before
+        any device wave."""
         plan = self._coax_plan
         if plan is None:
             return None
         return {"compile_count": plan.compile_count,
                 "dispatches": plan.dispatch_count,
+                "large_dispatches": plan.large_dispatch_count,
                 "bytes_h2d": plan.bytes_h2d,
                 "bytes_d2h": plan.bytes_d2h}
 

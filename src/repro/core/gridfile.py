@@ -140,9 +140,10 @@ class BatchStats:
     ``fallbacks`` counts CPU-oracle device waves that overflowed
     ``cell_cap`` and were answered by the numpy path (DESIGN.md §4
     submit-time overflow contract; the Pallas route has none);
-    ``hit_overflows`` counts individual queries whose exact device hit
-    count exceeded ``hit_cap`` and were re-answered on the host at drain
-    time (§4 drain-time overflow contract).
+    ``hit_overflows`` counts individual queries answered again on the host
+    after the device found more hits than its buffer holds; the device
+    plans answer those queries themselves, by their drain-time large pass,
+    which ``large_results`` counts (§4 drain-time overflow contract).
     """
     queries: int = 0
     cells_probed: int = 0
@@ -150,6 +151,7 @@ class BatchStats:
     backend: str = "numpy"
     fallbacks: int = 0
     hit_overflows: int = 0
+    large_results: int = 0
 
     def merge(self, other: "BatchStats") -> "BatchStats":
         return BatchStats(
@@ -159,6 +161,7 @@ class BatchStats:
             backend=self.backend,
             fallbacks=self.fallbacks + other.fallbacks,
             hit_overflows=self.hit_overflows + other.hit_overflows,
+            large_results=self.large_results + other.large_results,
         )
 
 
@@ -518,7 +521,8 @@ class GridFile:
                 self.last_batch_stats = BatchStats(
                     queries=b, cells_probed=s["cells_probed"],
                     rows_scanned=s["rows_scanned"], backend="device",
-                    hit_overflows=s["hit_overflows"])
+                    hit_overflows=s["hit_overflows"],
+                    large_results=s["large_results"])
                 return out_q, out_r
             fallbacks = 1                   # CPU-oracle cell_cap overflow
         return self._query_batch_numpy(nav_rects, filter_rects, fallbacks)

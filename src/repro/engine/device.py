@@ -51,17 +51,23 @@ overlap the previous wave's drain (the executor/server double-buffering
 schedule, depth 2).
 
 Overflow contracts (both exact):
-  * ``hit_cap``  — detected at DRAIN from the exact device counts; only the
-    overflowing queries are re-answered on the host FROM CAPTURED STATE
-    (frozen grids + the tombstone set and delta log captured at submit), so
-    interleaved writes between submit and drain cannot shift the wave's
-    snapshot (``hit_overflows`` stat).  The re-answer must find exactly the
-    device's count, or the drain raises.
+  * ``hit_cap``  — detected at DRAIN from the exact device counts.  The
+    large pass (``_PlanBase._large_pass``) scans each segment that holds
+    an overflowing query again, over the ticket's own segment arrays (its
+    images, liveness planes and delta image as submitted, so writes since
+    are invisible), and compacts every hit of those queries on the device
+    (``compact_range``), ``chunk`` slots a query a pass, ``chunk`` tiered
+    from the exact counts (``_hit_chunk``).  ``large_results`` counts the
+    queries; a wave without one runs no extra dispatch.
   * ``cell_cap`` — the CPU-oracle route only, whose gather work grows with
     candidate cells: detected at SUBMIT from the host probe; the whole wave
     is answered by the numpy path (``fallbacks`` stat).  The Pallas route
     reads its listed tiles, or every tile when the list does not fit, and
     answers every wave.
+
+The answer is assembled with no sort across the wave (``_assemble``):
+each query's ids go to its own stretch of the output, which is sorted
+alone.
 
 Shape bucketing: wave width pads to a pow2 bucket (min ``min_bucket``),
 grid and delta images to a pow2 row count (min 4096, one kernel word
@@ -90,10 +96,14 @@ from ..core.gridfile import BatchStats, f32_ceil
 from ..core.types import sorted_contains
 from ..kernels import ref
 from ..kernels._platform import on_cpu, resolve_interpret
-from ..kernels.fused_scan import (GROUP_ROWS, fused_scan_call, pad_to_lanes,
-                                  tile_rows, to_lanes)
+from ..kernels.fused_scan import (GROUP_ROWS, WORD_BITS, compact_range,
+                                  fused_scan_call, fused_scan_words,
+                                  pad_to_lanes, tile_rows, to_lanes)
 
 __all__ = ["DevicePlan", "CoaxDevicePlan", "f32_floor"]
+
+LARGE_TIER = 16          # large-pass hit budgets are hit_cap·16^k ...
+LARGE_CHUNK = 1 << 21    # ... slots a query, at most this many a pass
 
 
 def f32_floor(x: np.ndarray) -> np.ndarray:
@@ -141,27 +151,86 @@ def _wave_program(segs, config):
     exactness-preserving.
     """
     out = []
-    for seg, (hit_cap, probe, has_sort, use_pallas, interpret,
-              gw, nw) in zip(segs, config):
-        kwargs = {}
-        if probe:
-            kwargs.update(coords=seg["coords"], first=seg["first"],
-                          last=seg["last"])
-        if has_sort:
-            kwargs.update(sv=seg["sv"], tband=seg["tband"])
-        if use_pallas:
-            if nw:
-                kwargs["work"] = seg["work"]
-            out.append(fused_scan_call(
-                seg["rows"], seg["flo"], seg["fhi"], seg["alive"],
-                hit_cap=hit_cap, interpret=interpret, **kwargs))
-        else:
-            if gw:
-                kwargs["gidx"] = seg["gidx"]
-            out.append(ref.fused_scan_ref(
-                seg["rows"], seg["flo"], seg["fhi"], seg["alive"],
-                hit_cap=hit_cap, **kwargs))
+    for seg, cfg in zip(segs, config):
+        args, kwargs = _scan_args(seg, cfg)
+        hit_cap, use_pallas, interpret = cfg[0], cfg[3], cfg[4]
+        out.append(
+            fused_scan_call(*args, hit_cap=hit_cap, interpret=interpret,
+                            **kwargs) if use_pallas
+            else ref.fused_scan_ref(*args, hit_cap=hit_cap, **kwargs))
     return tuple(out)
+
+
+def _words_program(seg, config):
+    """The large-answer pass's scan of ONE segment: the same kernel (or
+    oracle) and the same inputs as the wave's, returning the uncompacted
+    hit words ``(Bp, N/32)`` that ``compact_range`` reads."""
+    args, kwargs = _scan_args(seg, config)
+    if config[3]:
+        return fused_scan_words(*args, interpret=config[4], **kwargs)
+    return ref.fused_scan_words_ref(*args, **kwargs)
+
+
+def _scan_args(seg, config):
+    """One segment's operands for the kernel or its oracle: the per-row
+    planes and bounds, and the stages its static ``config`` turns on."""
+    _, probe, has_sort, use_pallas, _, gw, nw = config
+    kwargs = {}
+    if probe:
+        kwargs.update(coords=seg["coords"], first=seg["first"],
+                      last=seg["last"])
+    if has_sort:
+        kwargs.update(sv=seg["sv"], tband=seg["tband"])
+    if use_pallas and nw:
+        kwargs["work"] = seg["work"]
+    if not use_pallas and gw:
+        kwargs["gidx"] = seg["gidx"]
+    return (seg["rows"], seg["flo"], seg["fhi"], seg["alive"]), kwargs
+
+
+def _hit_chunk(count: int, hit_cap: int, n_pad: int) -> int:
+    """Slots a query gets in one ``compact_range`` pass for a largest
+    answer of ``count`` hits: the first budget ``hit_cap·16^k`` that holds
+    it, at most ``LARGE_CHUNK`` and the image's padded rows.  Budgets 16x
+    apart keep the compiled shapes few, so answers that vary by tens of
+    percent share one; an answer past the chunk takes more passes of the
+    same shape."""
+    tier = hit_cap * LARGE_TIER
+    while tier < count:
+        tier *= LARGE_TIER
+    return min(tier, LARGE_CHUNK, n_pad)
+
+
+def _assemble(b: int, small, large):
+    """The wave's ``(query_ids, row_ids)``, grouped by query and each
+    query's ids ascending, with no sort across the wave.
+
+    ``small`` holds each segment's first-pass hits as ``(query, id)``
+    arrays grouped by query; ``large`` holds ``(query, ids)`` blocks from
+    the large pass.  Each query's hits are placed in its own stretch of the
+    output, then that stretch alone is sorted."""
+    total = np.zeros(b, np.int64)
+    for q, _ in small:
+        total += np.bincount(q, minlength=b)
+    for q, ids in large:
+        total[q] += ids.size
+    start = np.zeros(b + 1, np.int64)
+    np.cumsum(total, out=start[1:])
+    out = np.empty(int(start[-1]), np.int64)
+    fill = start[:-1].copy()
+    for q, ids in small:
+        if not q.size:
+            continue
+        cnt = np.bincount(q, minlength=b)
+        first = np.cumsum(cnt) - cnt
+        out[fill[q] + np.arange(q.size) - first[q]] = ids
+        fill += cnt
+    for q, ids in large:
+        out[fill[q]:fill[q] + ids.size] = ids
+        fill[q] += ids.size
+    for q in np.nonzero(total > 1)[0]:
+        out[start[q]:start[q + 1]].sort()
+    return np.repeat(np.arange(b, dtype=np.int64), total), out
 
 
 class _GridImage:
@@ -417,26 +486,14 @@ class _GridImage:
                 int(nw) if use_pallas and self.probe else 0)
 
 
-def _check_reanswer(dev_counts: np.ndarray, host_q: np.ndarray) -> None:
-    """The device's per-query counts are exact even past ``hit_cap``; the
-    host re-answer of those queries must find exactly as many rows."""
-    host = np.bincount(host_q, minlength=dev_counts.size)
-    if not np.array_equal(host, dev_counts):
-        raise RuntimeError(
-            f"device hit counts {dev_counts.tolist()} disagree with the "
-            f"host re-answer {host.tolist()}")
-
-
-def _extract_hits(counts: np.ndarray, hits: np.ndarray, cap: int,
-                  over: np.ndarray):
+def _extract_hits(counts: np.ndarray, hits: np.ndarray, cap: int):
     """Unpack one segment's compacted device hits: per-query row positions
-    for every non-overflowing query (overflowers are host re-answered)."""
-    take = np.where(over, 0, np.minimum(counts, cap))
-    if not take.sum():
-        return np.empty(0, np.int64), np.empty(0, np.int64)
-    valid = np.arange(cap)[None, :] < take[:, None]
-    q, c = np.nonzero(valid)
-    return q.astype(np.int64), hits[q, c].astype(np.int64)
+    for every query whose count fits the buffer (the large pass answers
+    the others), grouped by query."""
+    take = np.where(counts > cap, 0, counts)
+    q = np.repeat(np.arange(take.size), take)
+    slot = np.arange(q.size) - np.repeat(np.cumsum(take) - take, take)
+    return q, hits[q, slot]
 
 
 class _PlanBase:
@@ -452,7 +509,12 @@ class _PlanBase:
         # private to this plan instead of shared process-wide
         self._fn = jax.jit(functools.partial(_wave_program),
                            static_argnums=(1,))
+        self._words_fn = jax.jit(functools.partial(_words_program),
+                                 static_argnums=(1,))
+        self._compact_fn = jax.jit(functools.partial(compact_range),
+                                   static_argnames=("chunk",))
         self.dispatch_count = 0      # jitted wave-program launches (1/wave)
+        self.large_dispatch_count = 0   # large-pass launches (drain time)
         self.bytes_h2d = 0           # resident images + per-wave inputs
         self.bytes_d2h = 0           # drained compacted result buffers
 
@@ -464,13 +526,23 @@ class _PlanBase:
         ``compile_count`` flat across compactions and the launch/transfer
         accounting monotonic."""
         self._fn = other._fn
+        self._words_fn = other._words_fn
+        self._compact_fn = other._compact_fn
         self.dispatch_count = other.dispatch_count
+        self.large_dispatch_count = other.large_dispatch_count
         self.bytes_h2d += other.bytes_h2d
         self.bytes_d2h = other.bytes_d2h
 
     @property
     def compile_count(self) -> int:
-        """Distinct compiled wave shapes so far — the §4 cache-policy metric."""
+        """Distinct compiled shapes so far, the wave program's and the large
+        pass's — the §4 cache-policy metric."""
+        return (self.wave_compile_count + int(self._words_fn._cache_size())
+                + int(self._compact_fn._cache_size()))
+
+    @property
+    def wave_compile_count(self) -> int:
+        """Distinct compiled shapes of the wave program alone."""
         return int(self._fn._cache_size())
 
     def bucket(self, b: int) -> int:
@@ -549,6 +621,57 @@ class _PlanBase:
                                  stage="dispatch", backend="device")
         return res
 
+    def _large_pass(self, segs, cfgs, seg_np, qmaps):
+        """Every hit of each query whose count in a segment exceeds
+        ``hit_cap``, from the device (DESIGN.md §4).
+
+        Per such segment: one dispatch of the wave's own scan over the
+        ticket's segment arrays (``_words_program``: the images, liveness
+        and delta image as submitted, so later writes stay invisible), then
+        ``compact_range`` passes over the overflowing queries, ``kb`` of
+        them (``bucket``) and ``_hit_chunk`` slots each a pass.  Returns
+        ``(segment, query in the segment, hit positions)`` triples and the
+        number of wave queries they answer."""
+        jobs = [(i, np.nonzero(c > self.hit_cap)[0])
+                for i, (c, _, _) in enumerate(seg_np)]
+        jobs = [(i, sel) for i, sel in jobs if sel.size]
+        if not jobs:
+            return [], 0
+        out, wave_q = [], set()
+        with obs.span("device.large") as sp:
+            pending, h2d = [], 0
+            for i, sel in jobs:
+                counts = seg_np[i][0][sel]
+                top = int(counts.max())
+                words = self._words_fn(segs[i], cfgs[i])
+                chunk = _hit_chunk(top, self.hit_cap,
+                                   words.shape[1] * WORD_BITS)
+                rows = np.full(self.bucket(sel.size), np.iinfo(np.int32).max,
+                               np.int32)        # past the wave: no query
+                rows[:sel.size] = sel
+                h2d += rows.nbytes
+                parts = [self._compact_fn(words, rows, off, chunk=chunk)
+                         for off in range(0, top, chunk)]
+                self.large_dispatch_count += 1 + len(parts)
+                pending.append((i, sel, counts, parts))
+                wave_q.update((sel if qmaps[i] is None
+                               else qmaps[i][sel]).tolist())
+            self._count_h2d(h2d)
+            d2h = budget = 0
+            for i, sel, counts, parts in pending:
+                host = [np.asarray(p) for p in parts]
+                d2h += sum(h.nbytes for h in host)
+                budget += sum(h.size for h in host)
+                pos = host[0] if len(host) == 1 else np.concatenate(host, 1)
+                out += [(i, int(q), pos[j, :n])
+                        for j, (q, n) in enumerate(zip(sel, counts))]
+            self._count_d2h(d2h)
+            if sp is not None:
+                sp.args.update(queries=len(wave_q),
+                               hits=int(sum(c.sum() for _, _, c, _ in pending)),
+                               hit_budget=budget, bytes_d2h=d2h)
+        return out, len(wave_q)
+
     def _drain(self, res, bs):
         """Drain point: block, transfer the compacted buffers, count bytes.
         ``bs`` is the real (un-padded) query count per segment.  Returns
@@ -572,13 +695,16 @@ class _PlanBase:
                     out.append((counts, hits, scanned))
                 if sp is not None:
                     sp.args["bytes_d2h"] = d2h
-        self.bytes_d2h += d2h
-        obs.get_registry().counter(
-            "coax_device_bytes", "bytes moved across the PCIe/ICI boundary",
-            ("direction",)).inc(d2h, direction="d2h")
+        self._count_d2h(d2h)
         obs.stage_hist().observe(time.perf_counter() - t0,
                                  stage="transfer", backend="device")
         return out
+
+    def _count_d2h(self, nbytes: int) -> None:
+        self.bytes_d2h += nbytes
+        obs.get_registry().counter(
+            "coax_device_bytes", "bytes moved across the PCIe/ICI boundary",
+            ("direction",)).inc(nbytes, direction="d2h")
 
 
 class DevicePlan(_PlanBase):
@@ -596,7 +722,7 @@ class DevicePlan(_PlanBase):
         tile when the wave's list does not fit, so it has no such budget
         and answers every wave (§4).
     hit_cap : per-query device hit-buffer budget; queries whose exact count
-        exceeds it are re-answered on the host at drain time (§4).
+        exceeds it get all their hits from the drain-time large pass (§4).
     min_bucket : smallest wave bucket; B pads up to ``max(min_bucket,
         next_pow2(B))`` so steady-state widths share compiled shapes.
     use_pallas : route segments through the Pallas kernel; ``None`` picks
@@ -647,34 +773,29 @@ class DevicePlan(_PlanBase):
                                    self.interpret, gw, self._nw)
         res = self._dispatch([seg], [cfg])
         return {"b": b, "res": res, "cells": int(n_cells_q.sum()),
-                "nav": nav_rects, "filt": filter_rects}
+                "seg": seg, "cfg": cfg}
 
     def collect(self, ticket) -> Tuple[np.ndarray, np.ndarray, dict]:
-        """Drain one wave: block, transfer the compacted buffers, unpack,
-        and host re-answer any ``hit_cap`` overflowers from the frozen grid."""
+        """Drain one wave: block, transfer the compacted buffers, answer any
+        query over ``hit_cap`` by the large pass, and assemble."""
         b = ticket["b"]
         if ticket["res"] is None:
             return (np.empty(0, np.int64), np.empty(0, np.int64),
-                    {"cells_probed": 0, "rows_scanned": 0, "hit_overflows": 0})
-        ((counts, hits, scanned),) = self._drain(ticket["res"], [b])
-        over = counts > self.hit_cap
-        q, pos = _extract_hits(counts, hits, self.hit_cap, over)
-        out_q, out_r = q, self.grid.row_ids[pos]
-        rows_scanned = int(scanned.sum())
-        if over.any():                # exact per-query host re-answer (§4)
-            qsel = np.nonzero(over)[0]
-            with obs.span("device.reanswer", queries=int(qsel.size)):
-                qo, ro = self.grid._query_batch_numpy(
-                    ticket["nav"][qsel], ticket["filt"][qsel])
-                _check_reanswer(counts[qsel], qo)
-            rows_scanned += self.grid.last_batch_stats.rows_scanned
-            out_q = np.concatenate([out_q, qsel[qo]])
-            out_r = np.concatenate([out_r, ro])
-        order = np.lexsort((out_r, out_q))
+                    {"cells_probed": 0, "rows_scanned": 0,
+                     "hit_overflows": 0, "large_results": 0})
+        seg_np = self._drain(ticket["res"], [b])
+        large, n_large = self._large_pass([ticket["seg"]], [ticket["cfg"]],
+                                          seg_np, [None])
+        ((counts, hits, scanned),) = seg_np
+        with obs.span("device.assemble"):
+            ids = self.grid.row_ids
+            q, pos = _extract_hits(counts, hits, self.hit_cap)
+            out_q, out_r = _assemble(b, [(q, ids[pos])],
+                                     [(lq, ids[p]) for _, lq, p in large])
         stats = {"cells_probed": ticket["cells"],
-                 "rows_scanned": rows_scanned,
-                 "hit_overflows": int(over.sum())}
-        return out_q[order], out_r[order], stats
+                 "rows_scanned": int(scanned.sum()),
+                 "hit_overflows": 0, "large_results": n_large}
+        return out_q, out_r, stats
 
     def run_wave(self, nav_rects: np.ndarray, filter_rects: np.ndarray
                  ) -> Optional[Tuple[np.ndarray, np.ndarray, dict]]:
@@ -693,9 +814,9 @@ class CoaxDevicePlan(_PlanBase):
 
     The plan freezes the index's CURRENT epoch grids; write-state (liveness
     masks, delta image) refreshes lazily at submit when the delta-plane
-    counters move.  Tickets capture every host array a drain-time re-answer
-    needs, so collecting after further writes still answers from the wave's
-    submit-time snapshot.
+    counters move, by uploading fresh arrays.  Tickets keep the segment
+    arrays the wave was dispatched with, so the drain-time large pass
+    scans the wave's submit-time snapshot, whatever was written since.
 
     §9.3 pin retention: an ``EpochPin`` holds a strong reference to the
     plan that was live at pin time, so a compaction's ``adopt()`` of a new
@@ -720,14 +841,14 @@ class CoaxDevicePlan(_PlanBase):
                 self._count_h2d(img.bytes_resident)
         self._nw = self._list_width([self.p_img, self.o_img])
         self._dead_key = None
-        self._dead_host = np.empty(0, np.int64)
         self._delta_key = None
         self._delta = None
 
     def release_images(self) -> None:
         """Drop this plan's device images once its epoch is superseded.
-        Waves already dispatched hold their own references until they
-        finish, and ``collect`` never reads the images."""
+        Tickets of waves already dispatched hold their own references to
+        the images (their large pass scans them) until they are
+        collected."""
         self.p_img = self.o_img = self._delta = None
 
     # ------------------------------------------------------------------ #
@@ -739,10 +860,10 @@ class CoaxDevicePlan(_PlanBase):
         dead_key = (dp.n_tombstones, do.n_tombstones)
         nbytes = 0
         if dead_key != self._dead_key:
-            self._dead_host = self.index._dead_ids()
+            dead = self.index._dead_ids()
             for img in (self.p_img, self.o_img):
                 if img is not None:
-                    nbytes += img.set_alive(self._dead_host)
+                    nbytes += img.set_alive(dead)
             self._dead_key = dead_key
         delta_key = (dp.n_log, dp.n_log_dead, do.n_log, do.n_log_dead)
         if delta_key != self._delta_key:
@@ -756,8 +877,7 @@ class CoaxDevicePlan(_PlanBase):
                 rows_l = pad_to_lanes(rows.T, m_pad, np.inf, np.float32)
                 alive = pad_to_lanes(np.ones(m, np.int32), m_pad, 0, np.int32)
                 self._delta = {"rows_l": jnp.asarray(rows_l),
-                               "alive": jnp.asarray(alive),
-                               "rows": rows, "ids": ids}
+                               "alive": jnp.asarray(alive), "ids": ids}
                 nbytes += rows_l.nbytes + alive.nbytes
             else:
                 self._delta = None
@@ -897,11 +1017,8 @@ class CoaxDevicePlan(_PlanBase):
 
         res = self._dispatch(segs, cfgs) if segs else ()
         return {"b": b, "res": res, "ids": ids_list, "cells": cells_probed,
-                "qmaps": out["qmaps"], "bs": out["bs"],
-                "nav": nav_rects, "rects": rects, "touch": touch,
-                "dead": self._dead_host,
-                "delta": None if delta is None
-                else (delta["rows"], delta["ids"])}
+                "qmaps": out["qmaps"], "bs": out["bs"], "segs": segs,
+                "cfgs": cfgs}
 
     # ------------------------------------------------------------------ #
     def collect(self, ticket) -> Tuple[np.ndarray, np.ndarray, BatchStats]:
@@ -912,75 +1029,20 @@ class CoaxDevicePlan(_PlanBase):
             return (np.empty(0, np.int64), np.empty(0, np.int64),
                     BatchStats(queries=b, backend="device"))
         seg_np = self._drain(ticket["res"], ticket["bs"])
-        over = np.zeros(b, bool)
-        total = np.zeros(b, np.int64)          # exact device count per query
-        rows_scanned = 0
-        for (counts, _, scanned), qmap in zip(seg_np, ticket["qmaps"]):
-            o = counts > self.hit_cap
-            if qmap is None:
-                over |= o
-                total += counts
-            else:
-                over[qmap[o]] = True
-                total[qmap] += counts
-            rows_scanned += int(scanned.sum())
-        parts_q, parts_r = [], []
-        for (counts, hits, _), ids, qmap in zip(seg_np, ticket["ids"],
-                                                ticket["qmaps"]):
-            q, pos = _extract_hits(counts, hits, self.hit_cap,
-                                   over if qmap is None else over[qmap])
-            parts_q.append(q if qmap is None else qmap[q])
-            parts_r.append(ids[pos])
-        n_over = int(over.sum())
-        if n_over:
-            qsel = np.nonzero(over)[0]
-            with obs.span("device.reanswer", queries=n_over):
-                qo, ro, extra = self._reanswer(ticket, qsel)
-                _check_reanswer(total[qsel], qo)
-            parts_q.append(qsel[qo])
-            parts_r.append(ro)
-            rows_scanned += extra
-        out_q = np.concatenate(parts_q)
-        out_r = np.concatenate(parts_r)
-        order = np.lexsort((out_r, out_q))
+        qmaps = ticket["qmaps"]
+        large, n_large = self._large_pass(ticket["segs"], ticket["cfgs"],
+                                          seg_np, qmaps)
+        with obs.span("device.assemble"):
+            small = []
+            for (counts, hits, _), ids, qmap in zip(seg_np, ticket["ids"],
+                                                    qmaps):
+                q, pos = _extract_hits(counts, hits, self.hit_cap)
+                small.append((q if qmap is None else qmap[q], ids[pos]))
+            blocks = [(lq if qmaps[i] is None else int(qmaps[i][lq]),
+                       ticket["ids"][i][p]) for i, lq, p in large]
+            out_q, out_r = _assemble(b, small, blocks)
         stats = BatchStats(queries=b, cells_probed=ticket["cells"],
-                           rows_scanned=rows_scanned, backend="device",
-                           hit_overflows=n_over)
-        return out_q[order], out_r[order], stats
-
-    def _reanswer(self, ticket, qsel: np.ndarray):
-        """Exact host answer for ``hit_cap``-overflowing queries, replayed
-        from the ticket's CAPTURED state (frozen epoch grids + the tombstone
-        set and delta log as of submit) — writes applied between submit and
-        drain are invisible, preserving per-wave snapshot semantics."""
-        nav = ticket["nav"][qsel]
-        rects = ticket["rects"][qsel]
-        q_p, r_p = self.primary._query_batch_numpy(nav, rects)
-        extra = self.primary.last_batch_stats.rows_scanned
-        touch = ticket["touch"][qsel]
-        if touch.any() and self.outlier.n_rows:
-            sub = rects[touch]
-            q_o, r_o = self.outlier._query_batch_numpy(sub, sub)
-            extra += self.outlier.last_batch_stats.rows_scanned
-            if r_o.size:
-                q_p = np.concatenate([q_p, np.nonzero(touch)[0][q_o]])
-                r_p = np.concatenate([r_p, r_o])
-        dead = ticket["dead"]
-        if dead.size and r_p.size:
-            keep = ~sorted_contains(dead, r_p)
-            q_p, r_p = q_p[keep], r_p[keep]
-        if ticket["delta"] is not None:
-            drows, dids = ticket["delta"]
-            rows64 = drows.astype(np.float64)      # exact f64 upcast compare
-            hit = np.ones((qsel.size, dids.size), bool)
-            for j in range(drows.shape[1]):
-                v = rows64[:, j]
-                np.logical_and(hit, v[None, :] >= rects[:, j, 0][:, None],
-                               out=hit)
-                np.logical_and(hit, v[None, :] < rects[:, j, 1][:, None],
-                               out=hit)
-            qd, pos = np.nonzero(hit)
-            q_p = np.concatenate([q_p, qd.astype(np.int64)])
-            r_p = np.concatenate([r_p, dids[pos]])
-            extra += int(qsel.size) * int(dids.size)
-        return q_p, r_p, int(extra)
+                           rows_scanned=sum(int(s.sum())
+                                            for _, _, s in seg_np),
+                           backend="device", large_results=n_large)
+        return out_q, out_r, stats

@@ -70,8 +70,10 @@ class WaveStats:
     cells_probed: int = 0        # candidate (query, cell) pairs enumerated
     backend: str = "numpy"       # backend that answered this wave
     fallbacks: int = 0           # device waves re-answered by numpy (§4)
-    hit_overflows: int = 0       # queries whose hits overflowed the §4
-                                 # device hit buffer (re-answered at drain)
+    hit_overflows: int = 0       # queries answered again on the host after
+                                 # overflowing the §4 device hit buffer
+    large_results: int = 0       # queries over that buffer answered by the
+                                 # device plan's large pass (§4)
     epoch: int = 0               # snapshot epoch the wave was served from (§5)
     delta_rows: int = 0          # live delta-log rows unioned into the wave
     tombstones: int = 0          # tombstoned ids masked out of the wave
@@ -172,7 +174,10 @@ class BatchQueryExecutor:
         self._c_fb_waves = m.counter("fallback_waves",
                                      "waves with >=1 fallback")
         self._c_overflows = m.counter("hit_overflows",
-                                      "per-query device hit-buffer overflows")
+                                      "queries answered again on the host")
+        self._c_large = m.counter("large_results",
+                                  "queries answered by the device's large "
+                                  "pass")
         self._c_cache_hits = m.counter("cache_hits", "exact cache answers")
         self._c_cache_partial = m.counter("cache_partial",
                                           "containment cache answers")
@@ -271,6 +276,7 @@ class BatchQueryExecutor:
             backend=bs.backend if bs else self.backend,
             fallbacks=bs.fallbacks if bs else 0,
             hit_overflows=getattr(bs, "hit_overflows", 0) if bs else 0,
+            large_results=getattr(bs, "large_results", 0) if bs else 0,
             epoch=meta[0], delta_rows=meta[1], tombstones=meta[2],
             shards_hit=sum(1 for s in shard_stats if s[0] > 0),
             shard_stats=shard_stats,
@@ -288,6 +294,8 @@ class BatchQueryExecutor:
             self._c_fb_waves.inc()
         if ws.hit_overflows:
             self._c_overflows.inc(ws.hit_overflows)
+        if ws.large_results:
+            self._c_large.inc(ws.large_results)
         if ws.cache_hits:
             self._c_cache_hits.inc(ws.cache_hits)
         if ws.cache_partial:
@@ -434,6 +442,7 @@ class BatchQueryExecutor:
             "device_fallbacks": int(self._c_fallbacks.total()),
             "fallback_waves": int(self._c_fb_waves.total()),
             "hit_overflows": int(self._c_overflows.total()),
+            "large_results": int(self._c_large.total()),
             "total_s": total_s,
             "qps": total_q / total_s if total_s > 0 else 0.0,
             "wave_p50_ms": lat.quantile(0.5) * 1e3,

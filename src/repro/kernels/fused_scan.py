@@ -86,7 +86,8 @@ STEP_ROWS = STEP_GROUPS * GROUP_ROWS    # 32768 rows streamed per grid step
 DEFAULT_HIT_CAP = 1024
 LISTED_CHUNK = 32                       # hit slots a query locates per pass
 
-__all__ = ["fused_scan", "fused_scan_call", "compact_hits", "compact_listed",
+__all__ = ["fused_scan", "fused_scan_call", "fused_scan_words",
+           "compact_hits", "compact_listed", "compact_range",
            "to_lanes", "pad_to_lanes", "from_lanes", "lane_index",
            "padded_rows", "tile_rows", "GROUP_ROWS", "STEP_ROWS",
            "DEFAULT_HIT_CAP"]
@@ -341,13 +342,14 @@ def _plane_specs(planes, step, index):
     return specs
 
 
-def _full_scan(smem, planes, bp, kk, has_sort, hit_cap, interpret):
-    """Every tile for every query: the ``(Bp, tiles)`` grid."""
+def _full_words(smem, planes, bp, kk, has_sort, interpret):
+    """Every tile for every query: the ``(Bp, tiles)`` grid.  Returns the
+    hit words ``(Bp, N/4096, 128)`` and the resident candidate counts."""
     d, s, _ = planes[0].shape
     step = tile_rows(s * LANES) // LANES
     groups = step // WORD_BITS
     spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    words, scanned = pl.pallas_call(
+    return pl.pallas_call(
         _make_kernel(d, kk, has_sort, groups, 0),
         grid=(bp, s // step),          # steps innermost: resident scanned
         in_specs=[spec] * len(smem) + _plane_specs(
@@ -363,18 +365,23 @@ def _full_scan(smem, planes, bp, kk, has_sort, hit_cap, interpret):
         interpret=interpret,
         name="coax_fused_scan",
     )(*smem, *planes)
+
+
+def _full_scan(smem, planes, bp, kk, has_sort, hit_cap, interpret):
+    words, scanned = _full_words(smem, planes, bp, kk, has_sort, interpret)
     counts, hits = compact_hits(words, hit_cap)
     return counts, hits, jnp.sum(scanned, axis=(1, 2))[:, None]
 
 
-def _listed_scan(work, smem, planes, bp, kk, has_sort, hit_cap, interpret):
-    """The work list's items only: the ``(W,)`` grid."""
+def _listed_words(work, smem, planes, kk, has_sort, interpret):
+    """The work list's items only: the ``(W,)`` grid.  Returns the item
+    words ``(W, G, 128)`` and each item's candidate count."""
     d, s, _ = planes[0].shape
     nw = work.shape[0] // 2
     step = tile_rows(s * LANES) // LANES
     groups = step // WORD_BITS
     spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    words, scanned = pl.pallas_call(
+    return pl.pallas_call(
         _make_kernel(d, kk, has_sort, groups, nw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -393,7 +400,13 @@ def _listed_scan(work, smem, planes, bp, kk, has_sort, hit_cap, interpret):
         interpret=interpret,
         name="coax_fused_scan",
     )(work, *smem, *planes)
-    return compact_listed(words, scanned, work, bp, hit_cap, step * LANES)
+
+
+def _listed_scan(work, smem, planes, bp, kk, has_sort, hit_cap, interpret):
+    words, scanned = _listed_words(work, smem, planes, kk, has_sort,
+                                   interpret)
+    step = tile_rows(planes[0].shape[1] * LANES)
+    return compact_listed(words, scanned, work, bp, hit_cap, step)
 
 
 def fused_scan_call(
@@ -437,6 +450,100 @@ def fused_scan_call(
     listed = functools.partial(_listed_scan, work, smem, planes, bp, kk,
                                has_sort, hit_cap, interpret)
     return lax.cond(work[0] < 0, full, listed)
+
+
+def fused_scan_words(rows, flo, fhi, alive, coords=None, first=None,
+                     last=None, sv=None, tband=None, work=None, *,
+                     interpret: Optional[bool] = None):
+    """The megakernel's hit bitmap, uncompacted: ``(Bp, N/32)`` int32 words,
+    word ``w`` bit ``s`` being logical row ``32·w + s``, for every query.
+
+    The same scan as ``fused_scan_call`` (listed, or full when ``work[0]``
+    is negative or absent), for the large-answer pass, which compacts the
+    words with ``compact_range``.  A listed item's words land at its
+    ``(query, tile)``; tiles a query's list leaves out hold none of its
+    hits, so their words are zero."""
+    n = rows.shape[1] * LANES
+    bp = flo.shape[0]
+    smem, planes, kk, has_sort = _stage_operands(
+        rows, flo, fhi, alive, coords, first, last, sv, tband)
+    interpret = resolve_interpret(interpret)
+
+    def full():
+        words, _ = _full_words(smem, planes, bp, kk, has_sort, interpret)
+        return words.reshape(bp, n // WORD_BITS)
+
+    if work is None:
+        return full()
+
+    def listed():
+        words, _ = _listed_words(work, smem, planes, kk, has_sort,
+                                 interpret)
+        nw, groups, _ = words.shape
+        tiles = n // (groups * GROUP_ROWS)
+        qry = work[nw:]
+        dense = jnp.zeros((bp, tiles, groups * LANES), jnp.int32)
+        dense = dense.at[jnp.where(qry >= 0, qry, bp), work[:nw]].set(
+            words.reshape(nw, groups * LANES), mode="drop")
+        return dense.reshape(bp, n // WORD_BITS)
+
+    return lax.cond(work[0] < 0, full, listed)
+
+
+def _select_bit(word, rank):
+    """Bit index of the ``rank``-th (0-based) set bit of each uint32
+    ``word``: a binary search over the popcounts of its low halves."""
+    at = jnp.zeros(word.shape, jnp.uint32)
+    rank = rank.astype(jnp.uint32)
+    for width in (16, 8, 4, 2, 1):
+        low = lax.population_count(
+            (word >> at) & jnp.uint32((1 << width) - 1))
+        up = rank >= low
+        at = at + jnp.where(up, jnp.uint32(width), jnp.uint32(0))
+        rank = rank - jnp.where(up, low, jnp.uint32(0))
+    return at.astype(jnp.int32)
+
+
+@jax.named_scope("compact_range")
+def compact_range(words, sel, off, chunk: int):
+    """Positions of hits ``off .. off + chunk - 1`` (0-based, in row order)
+    of the selected queries: ``words (Bp, M)`` i32 from
+    ``fused_scan_words``, ``sel (kb,)`` i32 query rows (``>= Bp``: none),
+    ``off`` a traced scalar -> ``(kb, chunk)`` i32, ``-1`` past a query's
+    count.
+
+    Hit ``r`` lies in the last word whose first hit's rank ``s_w`` (the
+    running popcount before it) is at most ``r``.  So a value ``v_w`` of
+    that word reaches slot ``r`` as a running sum over slots of the
+    differences ``v_w - v_{w-1}``, each added at slot ``s_w``: the sum
+    telescopes to the last such word's value.  Three such sums give each
+    slot its word's index, bits and ``s_w``, and the bit is a five-step
+    search over popcounts.  The ``s_w`` ascend, so every add is one
+    sorted scatter and every read a cumsum, with no per-slot gather or
+    search, which a TPU runs slowly.  Word bits wrap in int32, exactly;
+    running counts are at most ``N``, which fits int32."""
+    w = jnp.take(words, sel, axis=0, mode="fill", fill_value=0)
+    kb, m = w.shape
+    pc = lax.population_count(w)
+    cw = jnp.cumsum(pc, axis=1)                          # inclusive
+    first = cw - pc                                      # s_w
+    # slots before the chunk fold into its first; past it, dropped
+    at = jnp.clip(first - off, 0, chunk)
+    row = jnp.broadcast_to(jnp.arange(kb)[:, None], (kb, m))
+
+    def running(step):
+        acc = jnp.zeros((kb, chunk), jnp.int32).at[row, at].add(
+            step, mode="drop", indices_are_sorted=True)
+        return jnp.cumsum(acc, axis=1)
+
+    def diff(v):
+        return v - jnp.pad(v, ((0, 0), (1, 0)))[:, :-1]
+
+    wi = running(jnp.ones_like(w)) - 1
+    word = lax.bitcast_convert_type(running(diff(w)), jnp.uint32)
+    slot = off + jnp.arange(chunk, dtype=jnp.int32)[None, :]
+    pos = wi * WORD_BITS + _select_bit(word, slot - running(diff(first)))
+    return jnp.where(slot < cw[:, -1:], pos, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("hit_cap", "interpret"))

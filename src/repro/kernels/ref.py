@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["range_scan_ref", "range_scan_batch_ref", "fused_scan_ref",
-           "grid_histogram_ref", "margin_split_ref"]
+           "fused_scan_words_ref", "grid_histogram_ref", "margin_split_ref"]
 
 
 def range_scan_ref(rows_t, rect_lo, rect_hi, window, *, tile: int = 512):
@@ -71,6 +71,29 @@ def fused_scan_ref(rows, flo, fhi, alive, coords=None, first=None,
     * **Bisect compaction** over the running hit count instead of the
       kernel's bitmap words — same prefix.
     """
+    hit, cand = _fused_hits(rows, flo, fhi, alive, coords, first, last,
+                            sv, tband, gidx)
+
+    running = jnp.cumsum(hit.astype(jnp.int32), axis=1)        # nondecreasing
+    counts = running[:, -1:]
+    scanned = cand.sum(axis=1, dtype=jnp.int32)[:, None]
+    targets = jnp.arange(1, hit_cap + 1, dtype=jnp.int32)
+    idx = jax.vmap(                        # j-th hit = first i with count j+1
+        lambda r: jnp.searchsorted(r, targets, side="left"))(running)
+    defined = targets[None, :] <= jnp.minimum(counts, hit_cap)
+    if gidx is not None:                   # local slot -> global row position
+        pos = jnp.take_along_axis(
+            gidx, jnp.minimum(idx, gidx.shape[1] - 1), axis=1)
+    else:
+        pos = idx
+    hits = jnp.where(defined, pos.astype(jnp.int32), -1)
+    return counts, hits, scanned
+
+
+def _fused_hits(rows, flo, fhi, alive, coords, first, last, sv, tband,
+                gidx):
+    """``fused_scan_ref``'s per-row test: ``(hit, cand)`` over every row
+    ``(Bp, N)`` in logical order, or over each query's ``gidx`` rows."""
     from .fused_scan import from_lanes, lane_index
 
     d = rows.shape[0]
@@ -104,21 +127,24 @@ def fused_scan_ref(rows, flo, fhi, alive, coords=None, first=None,
             v = from_lanes(sv)[None, :]
             cand = cand & (v >= tband[:, :1]) & (v < tband[:, 1:])
     hit = cand & inside
+    return hit, cand
 
-    running = jnp.cumsum(hit.astype(jnp.int32), axis=1)        # nondecreasing
-    counts = running[:, -1:]
-    scanned = cand.sum(axis=1, dtype=jnp.int32)[:, None]
-    targets = jnp.arange(1, hit_cap + 1, dtype=jnp.int32)
-    idx = jax.vmap(                        # j-th hit = first i with count j+1
-        lambda r: jnp.searchsorted(r, targets, side="left"))(running)
-    defined = targets[None, :] <= jnp.minimum(counts, hit_cap)
-    if gidx is not None:                   # local slot -> global row position
-        pos = jnp.take_along_axis(
-            gidx, jnp.minimum(idx, gidx.shape[1] - 1), axis=1)
-    else:
-        pos = idx
-    hits = jnp.where(defined, pos.astype(jnp.int32), -1)
-    return counts, hits, scanned
+
+def fused_scan_words_ref(rows, flo, fhi, alive, coords=None, first=None,
+                         last=None, sv=None, tband=None, gidx=None):
+    """Oracle for ``fused_scan.fused_scan_words``: ``(Bp, N/32)`` int32 hit
+    words, word ``w`` bit ``s`` being logical row ``32·w + s``.  With
+    ``gidx`` the gathered rows' hits are put back at their positions (the
+    pad slots all name one dead row, which never hits)."""
+    hit, _ = _fused_hits(rows, flo, fhi, alive, coords, first, last, sv,
+                         tband, gidx)
+    bp = flo.shape[0]
+    n = rows.shape[1] * rows.shape[2]
+    if gidx is not None:
+        hit = jnp.zeros((bp, n), bool).at[
+            jnp.arange(bp)[:, None], gidx].max(hit)
+    bits = hit.reshape(bp, n // 32, 32).astype(jnp.int32)
+    return jnp.sum(bits << jnp.arange(32, dtype=jnp.int32), axis=2)
 
 
 def grid_histogram_ref(x, d, params, *, buckets: int = 64):

@@ -140,13 +140,19 @@ def test_compile_cache_and_bucketed_shapes():
     assert plan is not None
     for _ in range(3):                       # repeated same-shape waves
         ex.execute(rects[:8])
-    assert plan.compile_count == 1, "steady-state wave recompiled"
+    assert plan.wave_compile_count == 1, "steady-state wave recompiled"
+    # rect 1 holds more rows than hit_cap: one large-pass scan and one
+    # compaction shape, compiled once
+    assert ex.stats()["large_results"] == 3
+    assert plan.compile_count == 3
 
     got = ex.execute(rects)                  # one call, waves of 8 and 4
-    assert plan.compile_count == 2, "second bucket shape should compile once"
+    assert plan.wave_compile_count == 2, \
+        "second bucket shape should compile once"
     for _ in range(2):
         ex.execute(rects)
-    assert plan.compile_count == 2, "repeat waves must hit the jit cache"
+    assert plan.wave_compile_count == 2, "repeat waves must hit the jit cache"
+    assert plan.compile_count == 4
 
     gf.backend = "numpy"
     for i, r in enumerate(rects):
@@ -332,8 +338,9 @@ def test_gather_oracle_matches_full_scan():
 
 
 def test_hit_cap_overflow_reanswer_matches_numpy():
-    """A tiny hit buffer forces per-query host re-answers at drain time;
-    results stay bit-identical and the overflow count is surfaced."""
+    """A tiny hit buffer sends queries to the drain-time large pass, which
+    answers them on the device: results stay bit-identical, nothing is
+    answered again on the host, and the large answers are counted."""
     ds = make_airline(6_000, seed=4)
     idx = COAXIndex(ds.data)
     rects = rects_for(ds.data, n=10, seed=5)     # includes a full-range rect
@@ -342,8 +349,66 @@ def test_hit_cap_overflow_reanswer_matches_numpy():
                       device_opts={"hit_cap": 16})
     q_d, r_d = idx_d.query_batch(rects)
     assert np.array_equal(q_d, q_n) and np.array_equal(r_d, r_n)
-    assert idx_d.last_batch_stats.backend == "device"   # not a wave fallback
-    assert idx_d.last_batch_stats.hit_overflows > 0
+    st = idx_d.last_batch_stats
+    assert st.backend == "device"                        # not a wave fallback
+    assert st.hit_overflows == 0
+    sizes = np.bincount(q_n, minlength=rects.shape[0])
+    assert st.large_results == int((sizes > 16).sum()) > 0
+    # the delta segment's own answers past the cap take the pass too
+    extra = make_airline(200, seed=9).data
+    for i in (idx, idx_d):
+        i.insert(extra)
+    q_n, r_n = idx.query_batch(rects)
+    q_d, r_d = idx_d.query_batch(rects)
+    assert np.array_equal(q_d, q_n) and np.array_equal(r_d, r_n)
+    assert idx_d.device_plan()._delta is not None
+
+
+@pytest.mark.parametrize("off, chunk", [(0, 4096), (0, 1), (37, 64),
+                                        (1000, 512), (5000, 4096)])
+def test_compact_range_matches_flatnonzero(off, chunk):
+    """``compact_range`` returns hits ``off .. off + chunk - 1`` of each
+    selected query in row order, ``-1`` past its count, and nothing for a
+    selection row past the wave."""
+    from repro.kernels.fused_scan import compact_range
+
+    rng = np.random.default_rng(17)
+    bits = rng.random((3, 2048 * 32)) < np.array([0.5, 0.02, 0.0])[:, None]
+    bits[1, :40] = True                    # a run across word boundaries
+    bits[2, -1] = True                     # the last bit of the last word
+    words = (bits.reshape(3, 2048, 32).astype(np.uint64)
+             << np.arange(32, dtype=np.uint64)).sum(-1)
+    words = words.astype(np.uint32).view(np.int32)
+    sel = np.array([2, 0, 1, 2**31 - 1], np.int32)
+    out = np.asarray(jax.jit(compact_range, static_argnames=("chunk",))(
+        words, sel, off, chunk=chunk))
+    assert out.shape == (4, chunk)
+    for row, q in enumerate(sel[:3]):
+        want = np.flatnonzero(bits[q])[off:off + chunk]
+        assert np.array_equal(out[row, :want.size], want)
+        assert (out[row, want.size:] == -1).all()
+    assert (out[3] == -1).all()
+
+
+def test_large_pass_takes_several_chunks(monkeypatch):
+    """Answers past ``LARGE_CHUNK`` take more compaction passes of one
+    compiled shape, and still equal the numpy path."""
+    from repro.engine import device
+
+    monkeypatch.setattr(device, "LARGE_CHUNK", 256)
+    ds = make_airline(6_000, seed=4)
+    rects = rects_for(ds.data, n=10, seed=5)     # includes a full-range rect
+    q_n, r_n = COAXIndex(ds.data).query_batch(rects)
+    idx = COAXIndex(ds.data, backend="device", device_opts={"hit_cap": 16})
+    q_d, r_d = idx.query_batch(rects)
+    assert np.array_equal(q_d, q_n) and np.array_equal(r_d, r_n)
+    assert np.bincount(q_n).max() > 4 * 256
+    plan = idx.device_plan()
+    # one scan shape and one compaction shape for each segment that overflowed
+    assert plan.compile_count - plan.wave_compile_count <= 4
+    stats = idx.device_stats()
+    assert stats["large_dispatches"] > 2 * (plan.compile_count
+                                            - plan.wave_compile_count)
 
 
 def test_one_dispatch_per_wave_and_device_stats():
@@ -368,15 +433,20 @@ def test_one_dispatch_per_wave_and_device_stats():
     assert idx.device_stats()["dispatches"] == n_waves + 1
 
 
-def test_resident_drain_across_waves_with_interleaved_writes():
+@pytest.mark.parametrize("route", [{}, {"use_pallas": True,
+                                       "interpret": True}],
+                         ids=["oracle", "pallas"])
+def test_resident_drain_across_waves_with_interleaved_writes(route):
     """≥3 in-flight waves with inserts/deletes/compaction landing between
     submit and drain: every wave must answer from the snapshot+delta state
     it was SUBMITTED from (per-wave snapshot semantics), even across an
-    epoch bump that swaps the grids out from under the in-flight tickets."""
+    epoch bump that swaps the grids out from under the in-flight tickets.
+    A small ``hit_cap`` sends the larger answers through the drain-time
+    large pass, which must scan the submit-time snapshot too."""
     rng = np.random.default_rng(31)
     ds = make_airline(6_000, seed=6)
     idx = COAXIndex(ds.data, backend="device",
-                    device_opts={"hit_cap": 64})  # small cap: overflow path
+                    device_opts={"hit_cap": 64, **route})
     rects = rects_for(ds.data, n=12, seed=11)     # under writes, too
     waves = [rects[0:4], rects[4:8], rects[8:12]]
     handles, expected = [], []
@@ -392,9 +462,15 @@ def test_resident_drain_across_waves_with_interleaved_writes():
         if i == 1:
             idx.compact()                         # epoch bump mid-stream
     assert idx.epoch > e0
+    large = 0
     for (q_e, r_e), h in zip(expected, handles):
         q_d, r_d = idx.query_batch_collect(h)
         assert np.array_equal(q_d, q_e) and np.array_equal(r_d, r_e)
+        assert idx.last_batch_stats.hit_overflows == 0
+        over = int((np.bincount(q_e, minlength=4) > 64).sum())
+        assert idx.last_batch_stats.large_results == over
+        large += over
+    assert large > 0
     # post-compaction wave: delta emptied then refilled; fresh plan epoch
     idx.backend = "numpy"
     q_e, r_e = idx.query_batch(rects[:6])
@@ -433,8 +509,10 @@ def test_server_pipelined_drain_device_equals_numpy():
 
 @pytest.mark.parametrize("plan", ["coax", "grid"])
 def test_reanswer_span_on_hit_cap_overflow(plan):
-    """A wave with a query over ``hit_cap`` records one ``device.reanswer``
-    span, after the wave's transfer, naming how many queries it answered."""
+    """A wave with a query over ``hit_cap`` records one ``device.large``
+    span, after the wave's transfer, naming how many queries it answered,
+    their hits, the slots it returned and the bytes it copied; then one
+    ``device.assemble`` span.  No ``device.reanswer`` span is left."""
     from repro import obs
 
     ds = make_airline(6_000, seed=4)
@@ -449,16 +527,27 @@ def test_reanswer_span_on_hit_cap_overflow(plan):
         query = lambda: idx.query_batch(rects[:, :3], rects)
     tr = obs.enable_tracing()
     try:
-        query()
+        q, _ = query()
     finally:
         obs.disable_tracing()
-    overflows = idx.last_batch_stats.hit_overflows
-    assert overflows > 0
+    st = idx.last_batch_stats
+    sizes = np.bincount(q, minlength=rects.shape[0])
+    assert st.hit_overflows == 0
+    assert st.large_results == int((sizes > 16).sum()) > 0
     evs = tr.events()
-    re = [e for e in evs if e["name"] == "device.reanswer"]
-    assert [e["args"]["queries"] for e in re] == [overflows]
+    assert not [e for e in evs if e["name"] == "device.reanswer"]
+    large = [e for e in evs if e["name"] == "device.large"]
+    assert len(large) == 1
+    args = large[0]["args"]
+    assert args["queries"] == st.large_results
+    # hits of a segment whose count fits the buffer come from the wave
+    assert 0 < args["hits"] <= int(sizes[sizes > 16].sum())
+    assert args["hit_budget"] >= args["hits"]
+    assert args["bytes_d2h"] == 4 * args["hit_budget"]
     transfer = [e for e in evs if e["name"] == "device.transfer"]
-    assert len(transfer) == 1 and transfer[0]["t1"] <= re[0]["t0"]
+    assert len(transfer) == 1 and transfer[0]["t1"] <= large[0]["t0"]
+    assemble = [e for e in evs if e["name"] == "device.assemble"]
+    assert len(assemble) == 1 and large[0]["t1"] <= assemble[0]["t0"]
 
 
 # --------------------------------------------------------------------- #
@@ -508,10 +597,12 @@ def test_listed_kernel_matches_full_scan(tiled_grid, case):
     """The work-list kernel (interpret mode) against the full scan and a
     brute-force scan of the grid: counts, hit prefixes and ``scanned``
     equal, with the list at the plan's width ``W`` (the image's 8 tiles);
-    a list past ``W`` takes the full-scan branch of the same program."""
+    a list past ``W`` takes the full-scan branch of the same program.  The
+    large pass's uncompacted words (``fused_scan_words``) hold every hit,
+    listed or not."""
     from repro.core.gridfile import f32_ceil
     from repro.engine.device import _GridImage
-    from repro.kernels.fused_scan import fused_scan
+    from repro.kernels.fused_scan import fused_scan, fused_scan_words
 
     gf = tiled_grid
     rects, bp, cap, dead = _listed_wave(gf, case)
@@ -550,9 +641,15 @@ def test_listed_kernel_matches_full_scan(tiled_grid, case):
     coords = np.stack([cell // 16, cell % 16], axis=1)
     sv = gf.sort_vals
     counts, hits, scanned = (np.asarray(x) for x in listed)
+    words = np.asarray(fused_scan_words(*ops, work=seg["work"],
+                                        interpret=True))
+    assert np.array_equal(words, np.asarray(
+        fused_scan_words(*ops, interpret=True)))
+    bits = np.unpackbits(words.view(np.uint8), axis=1, bitorder="little")
     for q in range(bp):
         if q >= b:                                      # padding: inert
             assert counts[q, 0] == scanned[q, 0] == 0
+            assert not bits[q].any()
             continue
         hit = alive & np.all((rows >= rects[q, :, 0])
                              & (rows < rects[q, :, 1]), axis=1)
@@ -561,6 +658,7 @@ def test_listed_kernel_matches_full_scan(tiled_grid, case):
                 & (sv >= f32_ceil(rects[q, 2, 0]))
                 & (sv < f32_ceil(rects[q, 2, 1])))
         want = np.nonzero(hit)[0]
+        assert np.array_equal(np.nonzero(bits[q])[0], want)
         assert counts[q, 0] == want.size
         assert scanned[q, 0] == cand.sum()
         take = min(want.size, cap)
@@ -597,7 +695,10 @@ def test_listed_waves_share_one_shape_per_bucket():
         obs.disable_tracing()
     plan = idx.device_plan()
     assert plan._nw == plan.p_img.tiles == 16
-    assert plan.compile_count == 1
+    assert plan.wave_compile_count == 1
+    # the full-range wave's answers come from the large pass, whose scan
+    # takes the full-scan branch of the same list
+    assert idx.last_batch_stats.large_results == 3
     args = [e["args"] for e in tr.events() if e["name"] == "device.inputs"]
     listed = [a["tiles_listed"] for a in args]
     image = [a["tiles_image"] for a in args]

@@ -12,7 +12,13 @@ here.  Three sets of shapes, each at the wave buckets B=4 and B=64:
 * the benchmark's airline table (80M rows x 8): primary 2^27 rows x k=3,
   outlier 2^23 x k=7;
 * the benchmark's OSM table (105M rows x 4): primary 2^27 x k=2, outlier
-  2^25 x k=3.
+  2^25 x k=3;
+* the benchmark's TPC-H ``lineitem`` at SF 10 (60M rows x 8): primary
+  2^26 x k=5, outlier 2^20 x k=7.
+
+The drain-time large pass compiles too, at the TPC-H primary's shapes,
+where every Q6 answer takes it: the segment's scan returning its hit
+words, and one compaction pass at the largest chunk.
 
 Every grid segment carries the plan's work list: ``W`` items, ``W`` being
 the tile count of the largest image (4,096 at 2^27 rows), so the
@@ -23,6 +29,7 @@ The topology is described inside a module-scoped fixture — never at import
 — so every xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
+import functools
 import os
 
 import jax
@@ -30,8 +37,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.engine.device import _wave_program
-from repro.kernels.fused_scan import DEFAULT_HIT_CAP, GROUP_ROWS, STEP_ROWS
+from repro.engine.device import LARGE_CHUNK, _wave_program, _words_program
+from repro.kernels.fused_scan import (DEFAULT_HIT_CAP, GROUP_ROWS, STEP_ROWS,
+                                      WORD_BITS, compact_range)
 
 DELTA_ROWS = GROUP_ROWS       # smallest delta image (holds up to 4096 rows)
 # (columns D, (padded rows, grid dims) of the primary, of the outlier grid)
@@ -39,6 +47,7 @@ TABLES = {
     "smoke": (8, (1 << 25, 3), (1 << 21, 7)),    # 18.4M / 1.6M rows
     "airline-80m": (8, (1 << 27, 3), (1 << 23, 7)),
     "osm-105m": (4, (1 << 27, 2), (1 << 25, 3)),
+    "tpch-sf10": (8, (1 << 26, 5), (1 << 20, 7)),
 }
 
 
@@ -115,3 +124,24 @@ def test_wave_program_compiles_for_v5e(one_chip, no_persistent_cache, bp,
     for counts, hits, scanned in out:
         assert counts.shape == scanned.shape == (bp, 1)
         assert hits.shape == (bp, DEFAULT_HIT_CAP)
+
+
+def test_large_pass_compiles_for_v5e(one_chip, no_persistent_cache):
+    d, (n_pad, k), _ = TABLES["tpch-sf10"]
+    seg, cfg = _segment(one_chip, 4, d, n_pad, k, sort=True,
+                        nw=n_pad // STEP_ROWS)
+    words_fn = jax.jit(_words_program, static_argnums=1)
+    compiled = words_fn.lower(seg, cfg).compile()
+    # the listed and the full-scan kernel, under the list's lax.cond
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    words = jax.eval_shape(words_fn, seg, cfg)
+    assert words.shape == (4, n_pad // WORD_BITS)
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    fn = jax.jit(compact_range, static_argnames=("chunk",))
+    args = (s(words.shape), s((4,)), s(()))
+    fn.lower(*args, chunk=LARGE_CHUNK).compile()
+    assert jax.eval_shape(functools.partial(fn, chunk=LARGE_CHUNK),
+                          *args).shape == (4, LARGE_CHUNK)
