@@ -504,6 +504,65 @@ def _select_bit(word, rank):
     return at.astype(jnp.int32)
 
 
+_EMPTY = np.iinfo(np.int32).min   # key of a lane no element holds
+
+
+def _shift(x, by: int, fill):
+    """``x`` moved ``by`` lanes toward its end (``by < 0``: toward its
+    start), ``fill`` in the lanes left behind; ``|by| < x.size``."""
+    pad = jnp.full((abs(by),), fill, x.dtype)
+    if by > 0:
+        return jnp.concatenate([pad, x[:-by]])
+    return jnp.concatenate([x[-by:], pad])
+
+
+def _route(key, planes, bit: int, left: bool):
+    """One round of a shift network: each element whose ``key`` has
+    ``bit`` set moves ``2^bit`` lanes, left or right, with its ``planes``;
+    a lane left empty gets key ``_EMPTY``, which has no such bit.  The
+    order of the rounds is the caller's guarantee that no two meet."""
+    step = -(1 << bit) if left else 1 << bit
+    key_in = _shift(key, step, _EMPTY)
+    arrive = ((key_in >> bit) & 1) == 1
+    leave = ((key >> bit) & 1) == 1
+    key = jnp.where(arrive, key_in, jnp.where(leave, _EMPTY, key))
+    return key, [jnp.where(arrive, _shift(p, step, 0), p) for p in planes]
+
+
+def _compact_row(w, off, chunk: int):
+    """``compact_range`` for one query's hit words ``w (M,)``."""
+    m = w.shape[0]
+    pc = lax.population_count(w)
+    cw = jnp.cumsum(pc)                                  # inclusive
+    first = cw - pc                                      # s_w
+    keep = (pc > 0) & (cw > off) & (first < off + chunk)
+    lead = off - jnp.min(jnp.where(keep, first, off))    # hits before off
+    lane = lax.iota(jnp.int32, m)
+    d = jnp.where(keep, lane + 1 - jnp.cumsum(keep, dtype=jnp.int32),
+                  _EMPTY)                                # dropped before
+    for bit in range((m - 1).bit_length()):
+        d, (w,) = _route(d, [w], bit, left=True)
+    c = min(m, chunk)              # no more words than slots hold hits
+    d = jnp.pad(d[:c], (0, chunk - c), constant_values=_EMPTY)
+    w = jnp.pad(w[:c], (0, chunk - c))
+    k = lax.iota(jnp.int32, chunk)
+    live = d >= 0
+    pc = jnp.where(live, lax.population_count(w), 0)
+    slot = jnp.maximum(jnp.cumsum(pc) - pc - lead, 0)
+    e = jnp.where(live, slot - k, _EMPTY)
+    planes = [k + d, w - _shift(w, 1, 0)]          # index, bits' difference
+    for bit in reversed(range((chunk - 1).bit_length())):
+        e, planes = _route(e, planes, bit, left=False)
+    placed = e >= 0
+    wi = lax.cummax(jnp.where(placed, planes[0], -1))
+    word = lax.bitcast_convert_type(
+        jnp.cumsum(jnp.where(placed, planes[1], 0)), jnp.uint32)
+    start = lax.cummax(jnp.where(placed, k, -1))
+    rank = jnp.where(start > 0, k - start, k + lead)
+    pos = wi * WORD_BITS + _select_bit(word, rank)
+    return jnp.where(off + k < cw[-1], pos, -1)
+
+
 @jax.named_scope("compact_range")
 def compact_range(words, sel, off, chunk: int):
     """Positions of hits ``off .. off + chunk - 1`` (0-based, in row order)
@@ -512,38 +571,31 @@ def compact_range(words, sel, off, chunk: int):
     ``off`` a traced scalar -> ``(kb, chunk)`` i32, ``-1`` past a query's
     count.
 
-    Hit ``r`` lies in the last word whose first hit's rank ``s_w`` (the
-    running popcount before it) is at most ``r``.  So a value ``v_w`` of
-    that word reaches slot ``r`` as a running sum over slots of the
-    differences ``v_w - v_{w-1}``, each added at slot ``s_w``: the sum
-    telescopes to the last such word's value.  Three such sums give each
-    slot its word's index, bits and ``s_w``, and the bit is a five-step
-    search over popcounts.  The ``s_w`` ascend, so every add is one
-    sorted scatter and every read a cumsum, with no per-slot gather or
-    search, which a TPU runs slowly.  Word bits wrap in int32, exactly;
-    running counts are at most ``N``, which fits int32."""
-    w = jnp.take(words, sel, axis=0, mode="fill", fill_value=0)
-    kb, m = w.shape
-    pc = lax.population_count(w)
-    cw = jnp.cumsum(pc, axis=1)                          # inclusive
-    first = cw - pc                                      # s_w
-    # slots before the chunk fold into its first; past it, dropped
-    at = jnp.clip(first - off, 0, chunk)
-    row = jnp.broadcast_to(jnp.arange(kb)[:, None], (kb, m))
+    Dense shift rounds, one query at a time, with no scatter and no
+    gather of one index a word or a slot (a TPU runs those one index
+    after another).  A word is kept if it holds a hit of the range; its
+    first hit's rank ``s_w`` is the running popcount before it.  (1) Each
+    kept word moves left by ``d_w``, the dropped words before it, one
+    round a bit of ``d_w`` from the lowest: for kept ``i < j``,
+    ``d_j - d_i`` is at most the words between them, so they never meet
+    and keep their order.  (2) The k-th kept word moves right to slot
+    ``max(s_w - off, 0)``, one round a bit from the highest: only the word
+    holding hit ``off`` starts before it, so the slots rise strictly, the
+    k-th is at least ``k``, and the shifts do not fall with ``k``; again
+    no two meet.  (3) Each slot takes the last word placed at or before
+    it: the index by a running max, the bits by a running sum of the
+    words' differences (it telescopes; int32 wraps exactly), and the bit
+    by a five-step search over popcounts.  Running counts are at most
+    ``N``, which fits int32."""
+    bp = words.shape[0]
 
-    def running(step):
-        acc = jnp.zeros((kb, chunk), jnp.int32).at[row, at].add(
-            step, mode="drop", indices_are_sorted=True)
-        return jnp.cumsum(acc, axis=1)
+    def one(q):
+        w = lax.dynamic_index_in_dim(words, jnp.clip(q, 0, bp - 1),
+                                     keepdims=False)
+        return _compact_row(jnp.where((q >= 0) & (q < bp), w, 0), off,
+                            chunk)
 
-    def diff(v):
-        return v - jnp.pad(v, ((0, 0), (1, 0)))[:, :-1]
-
-    wi = running(jnp.ones_like(w)) - 1
-    word = lax.bitcast_convert_type(running(diff(w)), jnp.uint32)
-    slot = off + jnp.arange(chunk, dtype=jnp.int32)[None, :]
-    pos = wi * WORD_BITS + _select_bit(word, slot - running(diff(first)))
-    return jnp.where(slot < cw[:, -1:], pos, -1)
+    return lax.map(one, sel)
 
 
 @functools.partial(jax.jit, static_argnames=("hit_cap", "interpret"))

@@ -390,6 +390,70 @@ def test_compact_range_matches_flatnonzero(off, chunk):
     assert (out[3] == -1).all()
 
 
+def _pack_words(bits):
+    """Hit bits ``(Q, 32·M)`` as the scan's int32 words: word ``w`` bit
+    ``s`` is row ``32·w + s``."""
+    q, n = bits.shape
+    words = (bits.reshape(q, n // 32, 32).astype(np.uint64)
+             << np.arange(32, dtype=np.uint64)).sum(-1)
+    return words.astype(np.uint32).view(np.int32)
+
+
+def _word_pattern(name, m, rng):
+    """One query's hit bits over ``m`` words."""
+    n = 32 * m
+    bits = np.zeros(n, bool)                     # "all_zero"
+    if name == "all_ones":
+        bits[:] = True
+    elif name == "last_word_one_hit":
+        bits[n - 19] = True
+    elif name == "runs":                         # dense runs, long gaps
+        for start in (5, n // 3, n - n // 5):
+            bits[start:start + 40 * 32 + 7] = True
+    elif name.startswith("density_"):
+        bits = rng.random(n) < float(name[len("density_"):])
+    return bits
+
+
+@pytest.mark.parametrize("m", [1024, 1000])     # 1000: rounds run off the end
+@pytest.mark.parametrize("pattern", [
+    "all_zero", "all_ones", "last_word_one_hit", "runs",
+    "density_0.001", "density_0.02", "density_0.5"])
+def test_compact_range_shift_rounds(pattern, m):
+    """``compact_range``'s shift rounds place every hit exactly: in one
+    pass of a chunk past the count, in several passes of a chunk below
+    it, and from an offset in the middle of a word's hits, each equal to
+    ``np.flatnonzero`` of the bits, ``-1`` past the count; a selection row
+    past the wave reads ``-1`` throughout."""
+    from repro.kernels.fused_scan import compact_range
+
+    rng = np.random.default_rng(m)
+    bits = np.stack([_word_pattern(pattern, m, rng) for _ in range(2)])
+    want = [np.flatnonzero(b) for b in bits]
+    words = _pack_words(bits)
+    sel = np.array([1, 7, 0], np.int32)          # 7 >= Bp: no query
+    fn = jax.jit(compact_range, static_argnames=("chunk",))
+
+    def check(got, off):
+        for row, q in ((0, 1), (2, 0)):
+            tail = want[q][off:off + got.shape[1]]
+            assert np.array_equal(got[row, :tail.size], tail), (row, off)
+            assert (got[row, tail.size:] == -1).all(), (row, off)
+        assert (got[1] == -1).all()
+
+    top = max(w.size for w in want)
+    for chunk in (32 * m, 777):
+        passes = [np.asarray(fn(words, sel, off, chunk=chunk))
+                  for off in range(0, max(top, 1), chunk)]
+        assert len(passes) == max(1, -(-top // chunk))
+        check(np.concatenate(passes, axis=1), 0)
+    # the first hit that shares its word with the one before it
+    same = np.flatnonzero(np.diff(want[1] // 32) == 0)
+    if same.size:
+        off = int(same[0]) + 1
+        check(np.asarray(fn(words, sel, off, chunk=777)), off)
+
+
 def test_large_pass_takes_several_chunks(monkeypatch):
     """Answers past ``LARGE_CHUNK`` take more compaction passes of one
     compiled shape, and still equal the numpy path."""

@@ -18,7 +18,8 @@ here.  Three sets of shapes, each at the wave buckets B=4 and B=64:
 
 The drain-time large pass compiles too, at the TPC-H primary's shapes,
 where every Q6 answer takes it: the segment's scan returning its hit
-words, and one compaction pass at the largest chunk.
+words, and one compaction pass at the largest chunk, whose optimized HLO
+must hold no scatter or gather with an index per word or per slot.
 
 Every grid segment carries the plan's work list: ``W`` items, ``W`` being
 the tile count of the largest image (4,096 at 2^27 rows), so the
@@ -30,7 +31,9 @@ The topology is described inside a module-scoped fixture — never at import
 runs this file loads the TPU library.
 """
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -126,7 +129,23 @@ def test_wave_program_compiles_for_v5e(one_chip, no_persistent_cache, bp,
         assert hits.shape == (bp, DEFAULT_HIT_CAP)
 
 
-def test_large_pass_compiles_for_v5e(one_chip, no_persistent_cache):
+@pytest.fixture(scope="module")
+def tpch_compaction(one_chip, no_persistent_cache):
+    """``compact_range`` compiled at the TPC-H primary's shapes: the
+    segment's words for a bucket of 4, one pass of ``LARGE_CHUNK`` slots."""
+    _, (n_pad, _), _ = TABLES["tpch-sf10"]
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    fn = jax.jit(compact_range, static_argnames=("chunk",))
+    args = (s((4, n_pad // WORD_BITS)), s((4,)), s(()))
+    out = jax.eval_shape(functools.partial(fn, chunk=LARGE_CHUNK), *args)
+    return fn.lower(*args, chunk=LARGE_CHUNK).compile(), out
+
+
+def test_large_pass_compiles_for_v5e(one_chip, no_persistent_cache,
+                                     tpch_compaction):
     d, (n_pad, k), _ = TABLES["tpch-sf10"]
     seg, cfg = _segment(one_chip, 4, d, n_pad, k, sort=True,
                         nw=n_pad // STEP_ROWS)
@@ -136,12 +155,28 @@ def test_large_pass_compiles_for_v5e(one_chip, no_persistent_cache):
     assert compiled.as_text().count("tpu_custom_call") >= 2
     words = jax.eval_shape(words_fn, seg, cfg)
     assert words.shape == (4, n_pad // WORD_BITS)
+    _, out = tpch_compaction
+    assert out.shape == (4, LARGE_CHUNK)
 
-    def s(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    fn = jax.jit(compact_range, static_argnames=("chunk",))
-    args = (s(words.shape), s((4,)), s(()))
-    fn.lower(*args, chunk=LARGE_CHUNK).compile()
-    assert jax.eval_shape(functools.partial(fn, chunk=LARGE_CHUNK),
-                          *args).shape == (4, LARGE_CHUNK)
+def _indexed_ops(hlo: str):
+    """``(op, name, index count)`` of every scatter and gather in an HLO
+    module's text: the element count of its second operand, the indices."""
+    shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", hlo))
+    ops = []
+    for name, op, operands in re.findall(
+            r"%([\w.\-]+) = \S+ (scatter|gather)\(([^)]*)\)", hlo):
+        idx = operands.split(",")[1].strip().lstrip("%")
+        dims = re.findall(r"\d+", shapes[idx].split("[", 1)[1])
+        ops.append((op, name, math.prod(int(x) for x in dims)))
+    return ops
+
+
+def test_large_pass_compaction_has_no_per_word_scatter(tpch_compaction):
+    """The compaction places hits by dense shift rounds: its optimized HLO
+    at the TPC-H shapes holds no scatter or gather with an index per word
+    or per slot (2^20 or more indices), which a TPU runs one by one."""
+    compiled, _ = tpch_compaction
+    per_word = [op for op in _indexed_ops(compiled.as_text())
+                if op[2] >= 1 << 20]
+    assert not per_word, per_word
